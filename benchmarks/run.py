@@ -19,6 +19,7 @@ import json
 import time
 
 RECORDS: list[dict] = []
+FAILED: list[str] = []          # bench groups whose subprocess failed
 
 
 def _timed(fn, *args, repeat=3, **kw):
@@ -172,8 +173,8 @@ def _subprocess_bench(payload: str, prefix: str, fail_name: str):
     leak into this process).  The payload sees jax/np/jnp and a
     `timed(fn) -> us` helper, and prints `<prefix>,<tag>,<us>,<us_rep>`
     rows; returns them as (tag, us, us_rep) tuples.  On a nonzero exit a
-    `fail_name` failure row is emitted instead (the gate then reports the
-    success rows as MISSING — a broken distributed path fails CI)."""
+    `fail_name` failure row is emitted instead and the whole run exits
+    non-zero once every group has run."""
     import os
     import subprocess
     import sys
@@ -183,6 +184,8 @@ def _subprocess_bench(payload: str, prefix: str, fail_name: str):
     pre = (
         'import os\n'
         'os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"\n'
+        # virtual host devices: never contend with the parent for a chip
+        'os.environ["JAX_PLATFORMS"] = "cpu"\n'
         'import sys, time\n'
         f'sys.path.insert(0, {src!r})\n'
         'import jax, numpy as np, jax.numpy as jnp\n'
@@ -200,10 +203,12 @@ def _subprocess_bench(payload: str, prefix: str, fail_name: str):
         # a hung collective must degrade to a failure row (the gate then
         # reports the success rows MISSING), not crash the whole sweep
         _row(fail_name, 0.0, "subprocess timed out after 600s")
+        FAILED.append(fail_name)
         return []
     if out.returncode != 0:
         err = (out.stderr.strip().splitlines() or ["unknown"])[-1]
         _row(fail_name, 0.0, f"subprocess failed: {err[:100]}")
+        FAILED.append(fail_name)
         return []
     rows = []
     for line in out.stdout.splitlines():
@@ -777,6 +782,8 @@ def main() -> None:
                     help="print bench group names (valid --only values) "
                          "and exit")
     args, _ = ap.parse_known_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     benches = {
         "table2": bench_table2, "fig7": bench_fig7, "fig9": bench_fig9,
@@ -813,6 +820,8 @@ def main() -> None:
             json.dump({"fast": args.fast, "only": args.only,
                        "records": RECORDS}, fh, indent=1)
             fh.write("\n")
+    if FAILED:
+        raise SystemExit(f"failed bench subprocesses: {', '.join(FAILED)}")
 
 
 if __name__ == "__main__":

@@ -15,9 +15,8 @@ TPU-native adaptation of BRAMAC's hybrid bit-serial & bit-parallel dataflow
     the current tile's compute — the eFSM overlap of Fig 5 that frees the
     "main BRAM" (HBM) for the rest of the system.
 
-Weights enter as int8 holding n-bit values (optionally packed 2-per-byte for
-4-bit — see `w_packed`); scales are applied in a fused epilogue on the last
-K step.
+Weights enter as int8 holding n-bit values; scales are applied in a fused
+epilogue on the last K step.
 """
 from __future__ import annotations
 
@@ -30,10 +29,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quant import num_digits
 
-# renamed TPUCompilerParams → CompilerParams across pallas releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 DEFAULT_BLOCK = (128, 128, 128)  # (bm, bk, bn) — MXU-aligned
 
 
@@ -45,8 +40,7 @@ def _digits(u: jax.Array, j: int, nd: int, signed: bool) -> jax.Array:
 
 
 def _kernel(x_ref, w_ref, xs_ref, ws_ref, out_ref, acc_ref, *,
-            bits_a: int, signed: bool, n_k: int, out_dtype, w_packed: bool,
-            bits_w: int):
+            bits_a: int, signed: bool, n_k: int, out_dtype):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -55,29 +49,16 @@ def _kernel(x_ref, w_ref, xs_ref, ws_ref, out_ref, acc_ref, *,
 
     x = x_ref[...]                                  # (bm, bk) int8 n-bit vals
     u = x.astype(jnp.int32) & ((1 << bits_a) - 1)   # unsigned bit view
-    if w_packed:
-        # int4 pair-packed along K: byte b at row r holds W[2r] (lo nibble)
-        # and W[2r+1] (hi nibble).  Sum over K is order-invariant, so we
-        # compute two half-K matmuls against the even/odd activation columns.
-        wp = w_ref[...].astype(jnp.int32)           # (bk//2, bn)
-        lo = wp & 0xF
-        w_lo = jnp.where(lo >= 8, lo - 16, lo).astype(jnp.int8)
-        hi = (wp >> 4) & 0xF
-        w_hi = jnp.where(hi >= 8, hi - 16, hi).astype(jnp.int8)
-        u_lo, u_hi = u[:, 0::2], u[:, 1::2]
-        halves = ((u_lo, w_lo), (u_hi, w_hi))
-    else:
-        halves = ((u, w_ref[...]),)
+    w = w_ref[...]
 
     nd = num_digits(bits_a)
     acc = acc_ref[...]
-    for uu, ww in halves:
-        for j in range(nd):                          # bit-serial digit passes
-            d = _digits(uu, j, nd, signed)
-            part = jax.lax.dot_general(              # bit-parallel MXU pass
-                d, ww, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32)
-            acc = acc + part * (4 ** j)              # shift-accumulate (P<<2)
+    for j in range(nd):                              # bit-serial digit passes
+        d = _digits(u, j, nd, signed)
+        part = jax.lax.dot_general(                  # bit-parallel MXU pass
+            d, w, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32)
+        acc = acc + part * (4 ** j)                  # shift-accumulate (P<<2)
     acc_ref[...] = acc
 
     @pl.when(k == n_k - 1)
@@ -89,16 +70,16 @@ def _kernel(x_ref, w_ref, xs_ref, ws_ref, out_ref, acc_ref, *,
 @functools.partial(
     jax.jit,
     static_argnames=("bits_a", "bits_w", "signed", "block", "out_dtype",
-                     "w_packed", "interpret"))
+                     "interpret"))
 def bramac_matmul(x_q: jax.Array, w_q: jax.Array, x_scale: jax.Array,
                   w_scale: jax.Array, *, bits_a: int, bits_w: int,
                   signed: bool = True, block=DEFAULT_BLOCK,
-                  out_dtype=jnp.float32, w_packed: bool = False,
+                  out_dtype=jnp.float32,
                   interpret: bool = False) -> jax.Array:
     """Quantized matmul  (M,K)·(K,N) → (M,N) via the BRAMAC dataflow.
 
     x_q:     (M, K) int8 holding bits_a-bit values.
-    w_q:     (K, N) int8 (or (K//2, N) pair-packed int8 when w_packed).
+    w_q:     (K, N) int8 holding bits_w-bit values.
     x_scale: (M, 1) or (1, 1) f32 per-row activation scales.
     w_scale: (1, N) or (1, 1) f32 per-column weight scales.
     """
@@ -108,34 +89,28 @@ def bramac_matmul(x_q: jax.Array, w_q: jax.Array, x_scale: jax.Array,
     N = w_q.shape[-1]
     if M % bm or K % bk or N % bn:
         raise ValueError(f"shape ({M},{K},{N}) not divisible by block {block}")
-    if w_packed and bits_w != 4:
-        raise ValueError("packed storage implemented for 4-bit weights")
     n_k = K // bk
     grid = (M // bm, N // bn, n_k)
 
     xs = jnp.broadcast_to(x_scale.astype(jnp.float32), (M, 1))
     ws = jnp.broadcast_to(w_scale.astype(jnp.float32), (1, N))
 
-    w_spec = (pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)) if w_packed
-              else pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)))
-
     kernel = functools.partial(
-        _kernel, bits_a=bits_a, signed=signed, n_k=n_k, out_dtype=out_dtype,
-        w_packed=w_packed, bits_w=bits_w)
+        _kernel, bits_a=bits_a, signed=signed, n_k=n_k, out_dtype=out_dtype)
 
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),   # activations
-            w_spec,                                            # weights
+            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),   # weights
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),     # x scales
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),     # w scales
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],      # the dummy array
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_q, w_q, xs, ws)

@@ -17,10 +17,16 @@ from repro.kernels.bramac_matmul import bramac_matmul
 
 
 def _interpret() -> bool:
-    """Pallas interpret mode for non-TPU backends, resolved per call — not
-    frozen at import, so `jax.config.update("jax_platform_name", ...)` after
-    importing this module still selects the right dispatch."""
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode on the CPU backend, compiled kernels on TPU;
+    any other backend is refused rather than silently interpreted.
+    Resolved per call — not frozen at import, so
+    `jax.config.update("jax_platform_name", ...)` after importing this
+    module still selects the right dispatch."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels target TPU (compiled) or CPU "
+                           f"(interpret mode), not {backend!r}")
+    return backend == "cpu"
 
 
 def _pad_to(x, m, axis, value=0):
@@ -35,16 +41,15 @@ def _pad_to(x, m, axis, value=0):
 VMEM_BUDGET = 16 * 2**20          # v5e VMEM per core (bytes)
 
 
-def kernel_vmem_bytes(block: tuple[int, int, int], w_packed: bool = False,
-                      out_bytes: int = 4) -> int:
+def kernel_vmem_bytes(block: tuple[int, int, int], out_bytes: int = 4) -> int:
     """VMEM working set of one bramac_matmul grid point: activation tile
-    (int8) + resident weight tile (int8, halved when 4-bit-packed — the
-    dummy-array footprint) + int32 accumulator + output tile.  Block shapes
+    (int8) + resident weight tile (int8 — the dummy-array footprint) +
+    int32 accumulator + output tile.  Block shapes
     must keep this under VMEM_BUDGET with headroom for double-buffering
     (×2 on the streamed operands)."""
     bm, bk, bn = block
     x = bm * bk                   # int8
-    w = bk * bn // (2 if w_packed else 1)
+    w = bk * bn
     acc = bm * bn * 4
     out = bm * bn * out_bytes
     return 2 * x + 2 * w + acc + out   # ×2: grid-pipeline double buffers
@@ -62,10 +67,10 @@ def pick_block(M: int, K: int, N: int) -> tuple[int, int, int]:
 
 def quant_matmul(x_q, w_q, x_scale, w_scale, *, bits_a: int, bits_w: int,
                  signed: bool = True, out_dtype=jnp.float32,
-                 w_packed: bool = False, use_kernel: bool = True):
+                 use_kernel: bool = True):
     """Quantized (M,K)x(K,N) matmul via the BRAMAC Pallas kernel.
 
-    Pads to block multiples, runs the kernel (interpret mode off-TPU), and
+    Pads to block multiples, runs the kernel (interpret mode on CPU), and
     slices back. When use_kernel=False runs the pure-jnp digit reference
     (useful under jit-of-vmap where pallas interpret mode is slow).
     """
@@ -73,16 +78,14 @@ def quant_matmul(x_q, w_q, x_scale, w_scale, *, bits_a: int, bits_w: int,
     # as a static arg, so flipping the backend after import retraces.
     return _quant_matmul(x_q, w_q, x_scale, w_scale, bits_a=bits_a,
                          bits_w=bits_w, signed=signed, out_dtype=out_dtype,
-                         w_packed=w_packed, use_kernel=use_kernel,
-                         interpret=_interpret())
+                         use_kernel=use_kernel, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("bits_a", "bits_w", "signed",
-                                             "out_dtype", "w_packed",
-                                             "use_kernel", "interpret"))
+                                             "out_dtype", "use_kernel",
+                                             "interpret"))
 def _quant_matmul(x_q, w_q, x_scale, w_scale, *, bits_a: int, bits_w: int,
-                  signed: bool, out_dtype, w_packed: bool, use_kernel: bool,
-                  interpret: bool):
+                  signed: bool, out_dtype, use_kernel: bool, interpret: bool):
     M, K = x_q.shape
     N = w_q.shape[-1]
     if not use_kernel:
@@ -93,18 +96,13 @@ def _quant_matmul(x_q, w_q, x_scale, w_scale, *, bits_a: int, bits_w: int,
     bm, bk, bn = pick_block(M, K, N)
     xp = _pad_to(_pad_to(x_q, bm, 0), bk, 1)
     wp = _pad_to(_pad_to(w_q, bk, 0), bn, 1)
-    if w_packed:
-        # pack along K (pack_bits packs the last axis → transpose twice);
-        # lo nibble of byte r = W[2r], hi nibble = W[2r+1] (kernel contract)
-        wp = quant.pack_bits(wp.T, bits_w).T
     xs = _pad_to(jnp.broadcast_to(jnp.asarray(x_scale, jnp.float32), (M, 1)),
                  bm, 0, value=1.0)
     ws = _pad_to(jnp.broadcast_to(jnp.asarray(w_scale, jnp.float32), (1, N)),
                  bn, 1, value=1.0)
     out = bramac_matmul(xp, wp, xs, ws, bits_a=bits_a, bits_w=bits_w,
                         signed=signed, block=(bm, bk, bn),
-                        out_dtype=out_dtype, w_packed=w_packed,
-                        interpret=interpret)
+                        out_dtype=out_dtype, interpret=interpret)
     return out[:M, :N]
 
 

@@ -50,10 +50,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ops import _interpret
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; alias
-# whichever this container ships (same guard as kernels/bramac_matmul.py).
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+
+def _row(b, *_scalars):
+    """Block index of sequence b's (1, ...) row; the scalar-prefetch refs
+    (tables, n_pages, lengths) that Pallas also passes are unused here."""
+    return b, 0, 0
 
 
 def _online_update(carry, s, v_tile):
@@ -80,12 +81,13 @@ def _fp_kernel(tables_ref, n_ref, len_ref, q_ref, k_hbm, v_hbm, out_ref,
                k_scr, v_scr, sems, *, page_size, hkv):
     H, hd = q_ref.shape[1], q_ref.shape[2]
     g, ps = H // hkv, page_size
+    b = pl.program_id(0)
     q = q_ref[0].astype(jnp.float32).reshape(hkv, g, hd)
-    L = len_ref[0]
-    n_eff = jnp.minimum(n_ref[0], (L + ps - 1) // ps)
+    L = len_ref[b]
+    n_eff = jnp.minimum(n_ref[b], (L + ps - 1) // ps)
 
     def body(j, carry):
-        pid = tables_ref[0, j]
+        pid = tables_ref[b, j]
         ck = pltpu.make_async_copy(k_hbm.at[pid], k_scr, sems.at[0])
         cv = pltpu.make_async_copy(v_hbm.at[pid], v_scr, sems.at[1])
         ck.start()
@@ -119,19 +121,30 @@ def _q_kernel(tables_ref, n_ref, len_ref, q_ref, qs_ref, k_hbm, ks_hbm,
     final max, then the quantized accumulation).  The extra walks keep
     every partial bit-comparable to the oracle: only the normalizer's
     float association order differs.  Traffic stays proportional to live
-    tokens; no fp copy of the cache is ever materialized."""
+    tokens; no fp copy of the cache is ever materialized.
+
+    Layout: a page's K/V tile is viewed as ``w = page_size * Hkv``
+    (row, head) pairs, matching the lane-dense ``(1, w)`` scale rows, and
+    every query head scores all ``w`` of them in one 2-D dot; pairs of
+    another KV head are masked to -1e30 like rows past the length, so
+    they add exact zeros.  Integer-valued f32 dots are exact here: a
+    page's partial sums stay below 2**24 and accumulate in int32."""
     H, hd = q_ref.shape[1], q_ref.shape[2]
     g, ps = H // hkv, page_size
-    q = q_ref[0].astype(jnp.float32).reshape(hkv, g, hd)   # int8 -> f32
-    qs = qs_ref[0].reshape(hkv, g)                         # per-row q scales
-    L = len_ref[0]
-    n_eff = jnp.minimum(n_ref[0], (L + ps - 1) // ps)
+    w = ps * hkv
+    b = pl.program_id(0)
+    q = q_ref[0].astype(jnp.float32)                       # (H, hd) ints
+    qs = qs_ref[0]                                         # (H, 1)
+    L = len_ref[b]
+    n_eff = jnp.minimum(n_ref[b], (L + ps - 1) // ps)
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, w), 1)
+    head_ok = col % hkv == jax.lax.broadcasted_iota(jnp.int32, (H, w), 0) // g
+    row = col // hkv                                       # row within page
 
     def load_scores(j):
-        """DMA page j's tiles; masked fp32 scores (Hkv, g, ps) exactly as
-        the oracle computes them, plus the resident int8 V tile and its
-        row scales."""
-        pid = tables_ref[0, j]
+        """DMA page j's tiles; masked fp32 scores (H, w) exactly as the
+        oracle computes them, plus the V tile and its row scales."""
+        pid = tables_ref[b, j]
         cps = [pltpu.make_async_copy(src.at[pid], dst, sems.at[i])
                for i, (src, dst) in enumerate(
                    ((k_hbm, k_scr), (ks_hbm, ks_scr),
@@ -140,59 +153,45 @@ def _q_kernel(tables_ref, n_ref, len_ref, q_ref, qs_ref, k_hbm, ks_hbm,
             c.start()
         for c in cps:
             c.wait()
-        kt = k_scr[...].transpose(1, 0, 2)                      # (Hkv,ps,hd)
-        kst = ks_scr[...].transpose(1, 0)                       # (Hkv,ps)
-        s = jax.lax.dot_general(q, kt.astype(jnp.float32),
-                                (((2,), (2,)), ((0,), (0,))),
+        k = k_scr[...].astype(jnp.float32).reshape(w, hd)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s = s * qs[..., None] * kst[:, None, :] / math.sqrt(hd)
-        rows = j * ps + jax.lax.broadcasted_iota(jnp.int32, (1, 1, ps), 2)
-        s = jnp.where(rows < L, s, -1e30)
-        vst = vs_scr[...].transpose(1, 0)                       # (Hkv,ps)
-        return s, v_scr[...].transpose(1, 0, 2), vst
+        s = s * qs * ks_scr[...] / math.sqrt(hd)
+        s = jnp.where(head_ok & (j * ps + row < L), s, -1e30)
+        return s, v_scr[...].astype(jnp.float32).reshape(w, hd), vs_scr[...]
 
     def max_body(j, m):
         s, _, _ = load_scores(j)
-        return jnp.maximum(m, jnp.max(s, axis=-1))
+        return jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
 
     m = jax.lax.fori_loop(0, n_eff, max_body,
-                          jnp.full((hkv, g), -jnp.inf, jnp.float32))
+                          jnp.full((H, 1), -jnp.inf, jnp.float32))
 
     def norm_body(j, carry):
         l, u = carry
-        s, _, vst = load_scores(j)
-        p = jnp.exp(s - m[..., None])
-        return l + jnp.sum(p, axis=-1), \
-            jnp.maximum(u, jnp.max(p * vst[:, None, :], axis=-1))
+        s, _, vs = load_scores(j)
+        p = jnp.exp(s - m)
+        return l + jnp.sum(p, axis=-1, keepdims=True), \
+            jnp.maximum(u, jnp.max(p * vs, axis=-1, keepdims=True))
 
     l, u = jax.lax.fori_loop(0, n_eff, norm_body,
-                             (jnp.zeros((hkv, g), jnp.float32),
-                              jnp.zeros((hkv, g), jnp.float32)))
+                             (jnp.zeros((H, 1), jnp.float32),
+                              jnp.zeros((H, 1), jnp.float32)))
     l = jnp.where(l > 0, l, 1.0)        # free slot (no pages): emit zeros
     # _quant_rows' scale over the probability row (probs * V row scales)
     pscale = jnp.maximum(u / l, 1e-6) / 127.0
 
     def acc_body(j, acc):
-        s, vt, vst = load_scores(j)
-        p = jnp.exp(s - m[..., None]) / l[..., None] * vst[:, None, :]
-        pq = jnp.clip(jnp.round(p / pscale[..., None]),
-                      -127, 127).astype(jnp.int32)
-        return acc + jax.lax.dot_general(
-            pq, vt.astype(jnp.int32), (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.int32)
+        s, v, vs = load_scores(j)
+        p = jnp.exp(s - m) / l * vs
+        pq = jnp.clip(jnp.round(p / pscale), -127, 127)
+        part = jax.lax.dot_general(pq, v, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        return acc + part.astype(jnp.int32)
 
     acc = jax.lax.fori_loop(0, n_eff, acc_body,
-                            jnp.zeros((hkv, g, hd), jnp.int32))
-    out = acc.astype(jnp.float32) * pscale[..., None]
-    out_ref[0] = out.reshape(H, hd).astype(out_ref.dtype)
-
-
-def _scalar_specs(max_pages):
-    """SMEM specs for (tables, n_pages, lengths) — one sequence's row."""
-    return [pl.BlockSpec((1, max_pages), lambda b: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda b: (b,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda b: (b,), memory_space=pltpu.SMEM)]
+                            jnp.zeros((H, hd), jnp.int32))
+    out_ref[0] = (acc.astype(jnp.float32) * pscale).astype(out_ref.dtype)
 
 
 def paged_decode(q, k_pool, v_pool, tables, n_pages, lengths):
@@ -213,17 +212,18 @@ def _paged_decode(q, k_pool, v_pool, tables, n_pages, lengths, *, interpret):
     kern = functools.partial(_fp_kernel, page_size=ps, hkv=Hkv)
     return pl.pallas_call(
         kern,
-        grid=(B,),
-        in_specs=_scalar_specs(tables.shape[1]) + [
-            pl.BlockSpec((1, H, hd), lambda b: (b, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b: (b, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, H, hd), _row),
+                      pl.BlockSpec(memory_space=pltpu.HBM),
+                      pl.BlockSpec(memory_space=pltpu.HBM)],
+            out_specs=pl.BlockSpec((1, H, hd), _row),
+            scratch_shapes=[pltpu.VMEM((ps, Hkv, hd), k_pool.dtype),
+                            pltpu.VMEM((ps, Hkv, hd), v_pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        scratch_shapes=[pltpu.VMEM((ps, Hkv, hd), k_pool.dtype),
-                        pltpu.VMEM((ps, Hkv, hd), v_pool.dtype),
-                        pltpu.SemaphoreType.DMA((2,))],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(tables, n_pages, lengths, q, k_pool, v_pool)
@@ -255,28 +255,36 @@ def _paged_decode_q(q_int8, q_scale, k_pool, k_scales, v_pool, v_scales,
     B, H, hd = q_int8.shape
     ps, Hkv = k_pool.shape[1], k_pool.shape[2]
     kern = functools.partial(_q_kernel, page_size=ps, hkv=Hkv)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     return pl.pallas_call(
         kern,
-        grid=(B,),
-        in_specs=_scalar_specs(tables.shape[1]) + [
-            pl.BlockSpec((1, H, hd), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b: (b, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b: (b, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, H, hd), _row),
+                      pl.BlockSpec((1, H, 1), _row),
+                      hbm, hbm, hbm, hbm],
+            out_specs=pl.BlockSpec((1, H, hd), _row),
+            scratch_shapes=[pltpu.VMEM((ps, Hkv, hd), jnp.int8),
+                            pltpu.VMEM((1, ps * Hkv), jnp.float32),
+                            pltpu.VMEM((ps, Hkv, hd), jnp.int8),
+                            pltpu.VMEM((1, ps * Hkv), jnp.float32),
+                            pltpu.SemaphoreType.DMA((4,))]),
         out_shape=jax.ShapeDtypeStruct((B, H, hd), out_dtype),
-        scratch_shapes=[pltpu.VMEM((ps, Hkv, hd), jnp.int8),
-                        pltpu.VMEM((ps, Hkv), jnp.float32),
-                        pltpu.VMEM((ps, Hkv, hd), jnp.int8),
-                        pltpu.VMEM((ps, Hkv), jnp.float32),
-                        pltpu.SemaphoreType.DMA((4,))],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tables, n_pages, lengths, q_int8, q_scale,
-      k_pool, k_scales, v_pool, v_scales)
+    )(tables, n_pages, lengths, q_int8, q_scale[..., None],
+      k_pool, _lane_rows(k_scales), v_pool, _lane_rows(v_scales))
+
+
+def _lane_rows(scales):
+    """(P, page_size, Hkv) row scales -> (P, 1, page_size * Hkv): one
+    lane-dense row per page, which the kernel can DMA (a (page_size, Hkv)
+    tile is narrower than the 128-lane tiling a DMA slice must respect).
+    The reshape is a relayout copy of the scale pool on every call."""
+    P, ps, hkv = scales.shape
+    return scales.reshape(P, 1, ps * hkv)
 
 
 # ---------------------------------------------------------------------------

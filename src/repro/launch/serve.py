@@ -15,8 +15,10 @@
         [--role prefill|decode|both --prefill-slots 4 --prefill-pages 16]
 
 All engine knobs funnel into ONE `EngineOptions` bundle
-(repro.runtime.options) — the launcher is the reference construction of
-the sectioned options surface, and the finish-reason / speculation
+(repro.runtime.options) — `engine_options` is the reference construction
+of the sectioned options surface (chip_smoke.py builds its engine through
+the same `parser` / `model_config` / `init_params` / `engine_options`
+functions), and the finish-reason / speculation
 summaries below come from the structured `RequestResult`s `Engine.run`
 returns.
 """
@@ -24,13 +26,16 @@ from __future__ import annotations
 
 import argparse
 import collections
+import functools
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.configs.base import ModelConfig
 from repro.core.bramac_linear import QuantConfig
+from repro.launch.cache import enable_compile_cache
 from repro.models import model as M
 from repro.parallel import sharding as shd
 from repro.runtime.options import (DebugOptions, DisaggOptions,
@@ -41,7 +46,9 @@ from repro.runtime.sampling import SamplingConfig
 from repro.runtime.serve import Engine
 
 
-def main():
+def parser() -> argparse.ArgumentParser:
+    """The launcher's command line; `chip_smoke.py` parses its serving
+    config through it too."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -151,8 +158,10 @@ def main():
     ap.add_argument("--check-invariants", action="store_true",
                     help="cross-check the host page-pool mirror against "
                          "the device allocator after every sync")
-    args = ap.parse_args()
+    return ap
 
+
+def model_config(args) -> ModelConfig:
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.page_size:
         cfg = cfg.replace(page_size=args.page_size)
@@ -160,14 +169,31 @@ def main():
         cfg = cfg.replace(quant=QuantConfig(enabled=True,
                                             bits_w=args.quant_bits,
                                             bits_a=args.quant_bits))
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    return cfg
+
+
+def init_params(cfg: ModelConfig, key, mesh=None):
+    """Random weights drawn by one jit'd program on the device: the fp32
+    draws are fused into the cast instead of each stacked weight being
+    materialized in fp32 first.  With a mesh the weights are created in
+    the engine's serving layout (`serve_rules`), so a model larger than
+    one chip never passes through a single device."""
+    init = functools.partial(M.init_params, cfg)
+    shardings = None
+    if mesh is not None:
+        ctx = shd.ShardingCtx(mesh, shd.serve_rules("pod" in mesh.axis_names))
+        shardings = shd.param_shardings(jax.eval_shape(init, key), ctx)
+    return jax.jit(init, out_shardings=shardings)(key)
+
+
+def engine_options(args) -> EngineOptions:
     mesh = None
-    if args.shard and args.shard != "0":    # "0" = off (PR 1's contract)
+    if args.shard and args.shard != "0":    # "0" = off
         try:
             mesh = shd.build_mesh(args.shard)
         except ValueError as e:
             raise SystemExit(f"--shard {args.shard!r}: {e}")
-    options = EngineOptions(
+    return EngineOptions(
         sampling=SamplingConfig(method=args.sampling,
                                 temperature=args.temperature,
                                 top_k=args.top_k, top_p=args.top_p),
@@ -198,6 +224,14 @@ def main():
                              prefill_slots=args.prefill_slots or None,
                              prefill_pages=args.prefill_pages or None),
         debug=DebugOptions(check_invariants=args.check_invariants))
+
+
+def main():
+    args = parser().parse_args()
+    enable_compile_cache()
+    cfg = model_config(args)
+    options = engine_options(args)
+    params = init_params(cfg, jax.random.PRNGKey(0), options.parallel.mesh)
     rng = np.random.default_rng(0)
     # the context manager releases the process-global sharding ctx even if
     # serving raises mid-run

@@ -1,4 +1,3 @@
 # Sharded execution: logical-axis rules + DP×TP(×EP) mesh builder
-# (sharding), version-portable collectives entry points (compat),
-# tensor-parallel quantized matmul (tp), expert-parallel quantized einsum
-# and MoE layer (ep).
+# (sharding), tensor-parallel quantized matmul (tp), expert-parallel
+# quantized einsum and MoE layer (ep).

@@ -54,7 +54,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import bramac_linear as bl
 from repro.core import quant
 from repro.parallel import sharding
-from repro.parallel.compat import shard_map
 
 
 def _ep_axis(mesh: Mesh, axis: str | None) -> str:
@@ -136,11 +135,11 @@ def ep_quant_einsum_edf(x: jax.Array, qw: quant.QuantizedTensor, *,
             return _dequant(bl.edf_accumulate(qx.values, wvb),
                             qx.scale, wsb, x.dtype)
 
-        return shard_map(expert_parallel, mesh=mesh,
-                         in_specs=(P(ax, dp_axis, None), P(ax, None, None),
-                                   P(ax, None, None)),
-                         out_specs=P(ax, dp_axis, None),
-                         check_vma=False)(x, wv, ws)
+        return jax.shard_map(expert_parallel, mesh=mesh,
+                             in_specs=(P(ax, dp_axis, None),
+                                       P(ax, None, None), P(ax, None, None)),
+                             out_specs=P(ax, dp_axis, None),
+                             check_vma=False)(x, wv, ws)
 
     if partition == "d":
         if wv.shape[1] % n:
@@ -153,10 +152,10 @@ def ep_quant_einsum_edf(x: jax.Array, qw: quant.QuantizedTensor, *,
         def contraction_parallel(xv, wvb):
             return jax.lax.psum(bl.edf_accumulate(xv, wvb), ax)
 
-        acc = shard_map(contraction_parallel, mesh=mesh,
-                        in_specs=(P(None, dp_axis, ax), P(None, ax, None)),
-                        out_specs=P(None, dp_axis, None),
-                        check_vma=False)(qx.values, wv)
+        acc = jax.shard_map(contraction_parallel, mesh=mesh,
+                            in_specs=(P(None, dp_axis, ax), P(None, ax, None)),
+                            out_specs=P(None, dp_axis, None),
+                            check_vma=False)(qx.values, wv)
         return _dequant(acc, qx.scale, ws, x.dtype)
 
     raise ValueError(f"partition must be 'e' or 'd', got {partition!r}")
@@ -347,10 +346,10 @@ def ep_moe(p, x, cfg, *, mesh: Mesh, axis: str | None = None,
     out_specs = (P(ax, None), P())
     if return_drops:
         out_specs += (P(ax, None),)
-    res = shard_map(shard_fn, mesh=mesh,
-                    in_specs=(P(ax, None), P(None, None), *w_specs),
-                    out_specs=out_specs,
-                    check_vma=False)(xf, p["router"], *weights)
+    res = jax.shard_map(shard_fn, mesh=mesh,
+                        in_specs=(P(ax, None), P(None, None), *w_specs),
+                        out_specs=out_specs,
+                        check_vma=False)(xf, p["router"], *weights)
     if return_drops:
         out, aux, keep = res
         return out.reshape(B, S, d), aux, keep

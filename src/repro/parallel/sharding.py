@@ -145,7 +145,12 @@ def build_mesh(spec: str | int | None = None, *, pod: int | None = None,
 
 
 def activate(mesh: Mesh, rules: dict | None = None) -> ShardingCtx:
+    """Make `mesh` + `rules` the process-wide sharding context.  The rules
+    act through `with_sharding_constraint`, which takes Auto mesh axes
+    only, so a mesh with Explicit axes (`jax.make_mesh`'s default) is
+    rebuilt over the same devices with Auto axes."""
     global _ACTIVE
+    mesh = Mesh(mesh.devices, mesh.axis_names)
     multi_pod = "pod" in mesh.axis_names
     _ACTIVE = ShardingCtx(mesh, rules or default_rules(multi_pod))
     return _ACTIVE
@@ -219,6 +224,14 @@ _PARAM_RULES: list[tuple[str, tuple]] = [
     (r"\b(w_gates|r_gates)$",    ("fsdp", "tp")),
 ]
 
+# Serving KV caches: attention K/V rows (and their int8 row scales) split
+# over KV heads on the tp axis, where the wk/wv columns that produce them
+# already are; every other cache leaf stays replicated.
+CACHE_RULES: list[tuple[str, tuple]] = [
+    (r"\b(k|v)$",                ("tp", None)),
+    (r"\b(ks|vs)$",              ("tp",)),
+]
+
 
 def _path_str(path) -> str:
     parts = []
@@ -232,12 +245,12 @@ def _path_str(path) -> str:
     return ".".join(parts)
 
 
-def spec_for(path_str: str, ndim: int) -> tuple:
+def spec_for(path_str: str, ndim: int, rules=None) -> tuple:
     # QuantizedTensor leaves: the .values/.scale arrays inherit the parent
     # weight's rule (right-aligned; non-divisible dims drop to None later).
     path_str = re.sub(r"\.(values|scale)$", "", path_str)
     chosen = None
-    for pattern, axes in _PARAM_RULES:
+    for pattern, axes in (_PARAM_RULES if rules is None else rules):
         if re.search(pattern, path_str):
             chosen = axes
     if chosen is None:
@@ -248,16 +261,20 @@ def spec_for(path_str: str, ndim: int) -> tuple:
     return (None,) * pad + tuple(chosen)
 
 
-def param_specs(params: Any) -> Any:
+def param_specs(params: Any, rules=None) -> Any:
     """Logical spec pytree mirroring `params`."""
     return jax.tree_util.tree_map_with_path(
-        lambda path, leaf: spec_for(_path_str(path), leaf.ndim), params)
+        lambda path, leaf: spec_for(_path_str(path), leaf.ndim, rules),
+        params)
 
 
-def param_shardings(params: Any, ctx: ShardingCtx | None = None) -> Any:
-    """NamedSharding pytree for jit in_shardings (divisibility-checked)."""
+def param_shardings(params: Any, ctx: ShardingCtx | None = None,
+                    rules=None) -> Any:
+    """NamedSharding pytree for jit in_shardings (divisibility-checked);
+    `rules` defaults to the parameter table (`CACHE_RULES` places KV
+    caches)."""
     ctx = ctx or _ACTIVE
-    specs = param_specs(params)
+    specs = param_specs(params, rules)
 
     def to_sharding(leaf, logical):
         fixed = []

@@ -32,7 +32,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels import ops
 from repro.parallel import sharding
-from repro.parallel.compat import shard_map
 
 
 def _tp_axis(mesh: Mesh, axis: str | None) -> str:
@@ -80,10 +79,10 @@ def tp_quant_matmul(x_q, w_q, x_scale, w_scale, *, mesh: Mesh,
                                    use_kernel=use_kernel)
             return jax.lax.psum(acc, ax)
 
-        acc = shard_map(row_parallel, mesh=mesh,
-                        in_specs=(P(None, ax), P(ax, None)),
-                        out_specs=P(None, None),
-                        check_vma=False)(x_q, w_q)
+        acc = jax.shard_map(row_parallel, mesh=mesh,
+                            in_specs=(P(None, ax), P(ax, None)),
+                            out_specs=P(None, None),
+                            check_vma=False)(x_q, w_q)
         return (acc.astype(jnp.float32) * xs * ws).astype(out_dtype)
 
     if partition == "n":
@@ -97,10 +96,10 @@ def tp_quant_matmul(x_q, w_q, x_scale, w_scale, *, mesh: Mesh,
                                     out_dtype=out_dtype,
                                     use_kernel=use_kernel)
 
-        return shard_map(col_parallel, mesh=mesh,
-                         in_specs=(P(None, None), P(None, ax),
-                                   P(None, None), P(None, ax)),
-                         out_specs=P(None, ax),
-                         check_vma=False)(x_q, w_q, xs, ws)
+        return jax.shard_map(col_parallel, mesh=mesh,
+                             in_specs=(P(None, None), P(None, ax),
+                                       P(None, None), P(None, ax)),
+                             out_specs=P(None, ax),
+                             check_vma=False)(x_q, w_q, xs, ws)
 
     raise ValueError(f"partition must be 'k' or 'n', got {partition!r}")

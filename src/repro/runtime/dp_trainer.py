@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.compat import shard_map
-
 BLOCK = 256
 
 
@@ -70,7 +68,7 @@ def make_dp_train_step(loss_fn, mesh: Mesh, axis: str = "data",
         new_err = jax.tree_util.tree_map(lambda e: e[None], new_err)
         return g_sync, new_err, loss
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(), P(axis), P(axis)),
         out_specs=(P(), P(axis), P()),

@@ -99,6 +99,7 @@ behind itself and `close()` is idempotent).
 """
 from __future__ import annotations
 
+import functools
 import time
 
 import jax
@@ -365,9 +366,17 @@ class Engine:
             else spc.empty_state(num_slots)
         self.state = init_slot_state(num_slots, self._stop_cap, mp,
                                      self.num_pages if paged else 0, draft0)
-        self.caches = M.init_cache(cfg, num_slots, max_seq,
-                                   num_pages=self.num_pages) if paged \
-            else M.init_cache(cfg, num_slots, max_seq)
+        make_caches = functools.partial(
+            M.init_cache, cfg, num_slots, max_seq,
+            num_pages=self.num_pages if paged else None)
+        if mesh is None:
+            self.caches = make_caches()
+        else:
+            # built in place on the mesh: an eager init would stage the
+            # whole cache on the first device
+            self.caches = jax.jit(make_caches, out_shardings=(
+                shd.param_shardings(jax.eval_shape(make_caches), self._ctx,
+                                    shd.CACHE_RULES)))()
         if self.disagg:
             self.p_state = init_slot_state(
                 self.prefill_slots, self._stop_cap, mp, self.prefill_pages,

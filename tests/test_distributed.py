@@ -28,7 +28,7 @@ def loss(p, b):
 ref_loss = loss(params, batch)
 ref_grad = jax.grad(lambda p: loss(p, batch))(params)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = shd.build_mesh("data=2,model=4")
 ctx = shd.activate(mesh)
 p_sh = shd.param_shardings(params, ctx)
 params_s = jax.device_put(params, p_sh)

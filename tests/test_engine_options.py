@@ -79,6 +79,28 @@ def test_build_merges_legacy_kwargs_over_base():
         EngineOptions.build(pages=4)
 
 
+def test_launcher_builds_engine_inputs_from_its_command_line(granite):
+    """`launch.serve`'s builders (shared with chip_smoke.py) turn the
+    command line into the config, the options and weights of the same
+    shapes as `M.init_params`, drawn inside one jit."""
+    from repro.launch import serve
+    cfg, params = granite
+    args = serve.parser().parse_args(
+        ["--arch", "granite-8b", "--smoke", "--slots", "3", "--max-seq",
+         "64", "--decode-kernel", "off", "--prefix-cache", "off"])
+    assert serve.model_config(args) == cfg
+    opts = serve.engine_options(args)
+    assert (opts.schedule.num_slots, opts.schedule.max_seq) == (3, 64)
+    assert opts.paging.decode_kernel is False
+    assert not opts.prefix.enabled and opts.parallel.mesh is None
+    got = serve.init_params(cfg, jax.random.PRNGKey(0))
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(params)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(params)):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype)
+
+
 def test_engine_validation_errors_survive_the_redesign(granite):
     """The exact error messages older callers match on still raise from
     Engine(...) whichever construction path is used."""

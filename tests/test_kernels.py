@@ -67,15 +67,13 @@ def test_bramac_matmul_unsigned_inputs():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want))
 
 
-def test_bramac_matmul_packed_weights():
-    rng = np.random.default_rng(4)
-    xq = jnp.asarray(rand_q(rng, 4, (16, 64)))
-    wq = jnp.asarray(rand_q(rng, 4, (64, 32)))
-    one = jnp.ones((1, 1), jnp.float32)
-    got = ops.quant_matmul(xq, wq, one, one, bits_a=4, bits_w=4,
-                           w_packed=True)
-    want = ref.quant_matmul_exact(xq, wq, one, one)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want))
+def test_kernels_refuse_backends_without_a_pallas_path(monkeypatch):
+    """Interpret mode is for the CPU only: on any backend that is neither
+    CPU nor TPU the kernels raise instead of silently interpreting."""
+    assert ops._interpret() is True                 # this suite: CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._interpret()
 
 
 @settings(max_examples=10, deadline=None)
@@ -123,9 +121,6 @@ def test_kernel_blocks_fit_vmem():
     for block in [(128, 128, 128), (256, 128, 512), (512, 512, 512)]:
         assert ops.kernel_vmem_bytes(block) < ops.VMEM_BUDGET, block
         assert all(b % 128 == 0 for b in block)
-    # packed int4 weights halve the resident tile
-    assert ops.kernel_vmem_bytes((128, 512, 512), w_packed=True) < \
-        ops.kernel_vmem_bytes((128, 512, 512), w_packed=False)
     # something must NOT fit, or the budget check is vacuous
     assert ops.kernel_vmem_bytes((1024, 1024, 2048)) > ops.VMEM_BUDGET
 

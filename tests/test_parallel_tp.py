@@ -1,11 +1,7 @@
 """Tensor-parallel quantized execution on 8 virtual CPU devices (the
-`multidevice` marker — see tests/conftest.py), plus unit tests for the
-version-portable shard_map compat layer."""
-import jax
+`multidevice` marker — see tests/conftest.py)."""
 import pytest
 from conftest import run_multidevice as run_sub
-
-from repro.parallel import compat
 
 
 @pytest.mark.multidevice
@@ -109,35 +105,30 @@ print("ENGINE_TP_OK")
     assert "ENGINE_TP_OK" in out
 
 
-# ---------------------------------------------------------------------------
-# compat shim units (in-process: a 1-device mesh needs no XLA flag)
-# ---------------------------------------------------------------------------
+@pytest.mark.multidevice
+def test_sharded_engine_builds_kv_cache_split_over_kv_heads():
+    """Engine(mesh=...) creates its paged KV pool in place on the mesh,
+    KV heads over `model` (where wk/wv put them), instead of staging the
+    whole cache on the first device; a head count the axis does not
+    divide stays replicated."""
+    out = run_sub("""
+from repro.configs import get_config
+from repro.models import model as M
+from repro.parallel import sharding as shd
+from repro.runtime.serve import Engine
 
-def test_compat_shard_map_runs_with_either_flag_spelling():
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    mesh = jax.make_mesh((1,), ("d",))
-    x = jnp.arange(4.0)
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-        f = compat.shard_map(lambda a: a * 2, mesh=mesh, in_specs=P("d"),
-                             out_specs=P("d"), **kw)
-        assert jnp.all(f(x) == x * 2)
-
-
-def test_compat_shard_map_conflicting_flags_raise():
-    from jax.sharding import PartitionSpec as P
-
-    mesh = jax.make_mesh((1,), ("d",))
-    with pytest.raises(ValueError, match="aliases"):
-        compat.shard_map(lambda a: a, mesh=mesh, in_specs=P("d"),
-                         out_specs=P("d"), check_vma=True, check_rep=False)
-
-
-def test_compat_translates_to_installed_spelling():
-    """The kwarg actually forwarded must be one the installed JAX accepts."""
-    impl, params = compat._impl()
-    has_new = "check_vma" in params
-    has_old = "check_rep" in params
-    assert has_new or has_old or params == frozenset()
-    # and the public entry accepted *both* spellings above regardless
+cfg = get_config("granite-8b", smoke=True)          # 2 KV heads
+params = M.init_params(cfg, jax.random.PRNGKey(0))
+for spec, want in (("data=4,model=2", "model"), ("data=2,model=4", None)):
+    with Engine(cfg, params, num_slots=2, max_seq=32,
+                mesh=shd.build_mesh(spec)) as eng:
+        k = eng.caches["pos0"]["k"]                 # (periods, P, ps, Hkv, hd)
+        assert len(k.sharding.device_set) == 8, k.sharding
+        got = tuple(k.sharding.spec) + (None,) * k.ndim
+        assert got[3] == want and got[:3] == (None,) * 3, (spec, got)
+        req = eng.submit([1, 2, 3], max_new_tokens=3)
+        eng.run()
+        assert req.done and len(req.out_tokens) == 3
+print("KV_SPLIT_OK")
+""")
+    assert "KV_SPLIT_OK" in out
