@@ -253,6 +253,12 @@ def main():
               f"({toks / dt:.1f} tok/s, quant="
               f"{'int%d' % args.quant_bits if args.quant_bits else 'off'}, "
               f"sampling={args.sampling})")
+        padded = eng.n_admit_calls * eng.prefill_slots * eng.prefill_chunk
+        print(f"  admit: {eng.n_admit_rows} prompt rows in "
+              f"{eng.n_admit_calls} calls of {eng.prefill_slots} slots x "
+              f"{eng.prefill_chunk} rows "
+              f"({100 * eng.n_admit_rows / max(padded, 1):.1f}% of the "
+              f"padded rows)")
         print(f"  {eng.n_syncs} host syncs for {eng.n_generated} tokens "
               f"({eng.n_syncs / max(eng.n_generated, 1):.2f} syncs/tok at "
               f"decode_steps={args.decode_steps}); mean ttft "

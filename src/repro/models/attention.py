@@ -242,61 +242,79 @@ def gqa(p, x, cfg, positions, cache=None, cache_pos=None, paged=None):
     """cache: {"k","v"} (B, S_max, Hkv, hd), or (P, page_size, Hkv, hd)
     pools when a `PagedKV` bundle is passed, or None (train/prefill).
 
+    The cache write is scoped `kv_write` beside, not inside, the
+    `attention` scope of the projections and the read, so a trace
+    groups every device op under one of the two.
+
     Returns (out, new_cache)."""
     B, S, _ = x.shape
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
-    q = dense(x, p["wq"], cfg.quant).reshape(B, S, H, hd)
-    k = dense(x, p["wk"], cfg.quant).reshape(B, S, Hkv, hd)
-    v = dense(x, p["wv"], cfg.quant).reshape(B, S, Hkv, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attention"):
+        q = dense(x, p["wq"], cfg.quant).reshape(B, S, H, hd)
+        k = dense(x, p["wk"], cfg.quant).reshape(B, S, Hkv, hd)
+        v = dense(x, p["wv"], cfg.quant).reshape(B, S, Hkv, hd)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     if cache is None:
-        out = causal_attention(q, k, v)
         new_cache = None
     elif "ks" in cache:                          # int8 KV cache (quant_kv)
-        # decode and prefill chunks both attend the stored int8 rows
-        # (earlier chunks only exist quantized) via the same masked path
         new_cache = _update_cache_q(cache, k, v, cache_pos, paged, positions)
-        if isinstance(paged, PagedKV) and paged.decode_kernel and S == 1:
-            # page-bounded pallas decode: the pool is read as stored int8,
-            # one (page_size, Hkv, hd) tile at a time (q row-quantized
-            # exactly as decode_attention_q would)
-            qq, qs = _quant_rows(q)
-            out = pk.paged_decode_q(
-                qq[:, 0], qs[:, 0], new_cache["k"], new_cache["ks"],
-                new_cache["v"], new_cache["vs"], paged.tables,
-                paged.n_pages, positions[:, 0] + 1, q.dtype)[:, None]
-        else:
-            view = new_cache if not isinstance(paged, PagedKV) else \
-                {key: paged_view(new_cache[key], paged) for key in new_cache}
-            out = decode_attention_q(q, view, positions)
-    elif isinstance(paged, DenseKV):
-        # speculative dense writes: per-position scatter with drop
-        kc = dense_update(cache["k"], k, positions, paged)
-        vc = dense_update(cache["v"], v, positions, paged)
-        out = chunk_attention(q, kc, vc, positions)
-        new_cache = {"k": kc, "v": vc}
-    elif paged is not None:
-        kc = paged_update(cache["k"], k, positions, paged)
-        vc = paged_update(cache["v"], v, positions, paged)
-        if paged.decode_kernel and S == 1:
+    else:
+        with jax.named_scope("kv_write"):
+            write = _row_writer(cache_pos, paged, positions)
+            new_cache = {"k": write(cache["k"], k), "v": write(cache["v"], v)}
+    with jax.named_scope("attention"):
+        kernel = isinstance(paged, PagedKV) and paged.decode_kernel \
+            and S == 1
+        if cache is None:
+            out = causal_attention(q, k, v)
+        elif "ks" in cache:
+            # decode and prefill chunks both attend the stored int8 rows
+            # (earlier chunks only exist quantized) via the same masked
+            # path
+            if kernel:
+                # page-bounded pallas decode: the pool is read as stored
+                # int8, one (page_size, Hkv, hd) tile at a time (q
+                # row-quantized exactly as decode_attention_q would)
+                qq, qs = _quant_rows(q)
+                out = pk.paged_decode_q(
+                    qq[:, 0], qs[:, 0], new_cache["k"], new_cache["ks"],
+                    new_cache["v"], new_cache["vs"], paged.tables,
+                    paged.n_pages, positions[:, 0] + 1, q.dtype)[:, None]
+            else:
+                view = new_cache if not isinstance(paged, PagedKV) else \
+                    {key: paged_view(new_cache[key], paged)
+                     for key in new_cache}
+                out = decode_attention_q(q, view, positions)
+        elif kernel:
             # page-bounded pallas decode kernel; the gather below stays
             # the parity oracle (and the Sq>1 prefill/verify path)
-            out = pk.paged_decode(q[:, 0], kc, vc, paged.tables,
-                                  paged.n_pages,
+            out = pk.paged_decode(q[:, 0], new_cache["k"], new_cache["v"],
+                                  paged.tables, paged.n_pages,
                                   positions[:, 0] + 1)[:, None]
+        elif isinstance(paged, PagedKV):
+            out = chunk_attention(q, paged_view(new_cache["k"], paged),
+                                  paged_view(new_cache["v"], paged),
+                                  positions)
         else:
-            out = chunk_attention(q, paged_view(kc, paged),
-                                  paged_view(vc, paged), positions)
-        new_cache = {"k": kc, "v": vc}
-    else:
-        kc = _update_cache(cache["k"], k, cache_pos)
-        vc = _update_cache(cache["v"], v, cache_pos)
-        # decode (S=1, positions == cache_pos) and prefill chunks share
-        # the same masked path over the cache
-        out = chunk_attention(q, kc, vc, positions)
-        new_cache = {"k": kc, "v": vc}
-    return dense(out.reshape(B, S, H * hd), p["wo"], cfg.quant), new_cache
+            # dense decode (S=1, positions == cache_pos), prefill chunks
+            # and the speculative dense window share the same masked path
+            # over the cache
+            out = chunk_attention(q, new_cache["k"], new_cache["v"],
+                                  positions)
+        out = dense(out.reshape(B, S, H * hd), p["wo"], cfg.quant)
+    return out, new_cache
+
+
+def _row_writer(pos, paged, positions):
+    """The cache write of one layout: a per-position scatter with drop
+    for the speculative dense window (`DenseKV`) and the paged pool, a
+    per-sequence dynamic_update_slice at `pos` for the dense cache."""
+    if isinstance(paged, DenseKV):
+        return lambda c, n: dense_update(c, n, positions, paged)
+    if paged is not None:
+        return lambda c, n: paged_update(c, n, positions, paged)
+    return lambda c, n: _update_cache(c, n, pos)
 
 
 def _update_cache(cache, new, pos):
@@ -345,22 +363,12 @@ def _quant_rows(x):
 
 
 def _update_cache_q(cache, k, v, pos, paged=None, positions=None):
-    kq, ks = _quant_rows(k)
-    vq, vs = _quant_rows(v)
-    if isinstance(paged, DenseKV):
-        return {"k": dense_update(cache["k"], kq, positions, paged),
-                "ks": dense_update(cache["ks"], ks, positions, paged),
-                "v": dense_update(cache["v"], vq, positions, paged),
-                "vs": dense_update(cache["vs"], vs, positions, paged)}
-    if paged is not None:
-        return {"k": paged_update(cache["k"], kq, positions, paged),
-                "ks": paged_update(cache["ks"], ks, positions, paged),
-                "v": paged_update(cache["v"], vq, positions, paged),
-                "vs": paged_update(cache["vs"], vs, positions, paged)}
-    return {"k": _update_cache(cache["k"], kq, pos),
-            "ks": _update_cache(cache["ks"], ks, pos),
-            "v": _update_cache(cache["v"], vq, pos),
-            "vs": _update_cache(cache["vs"], vs, pos)}
+    with jax.named_scope("kv_write"):
+        kq, ks = _quant_rows(k)
+        vq, vs = _quant_rows(v)
+        write = _row_writer(pos, paged, positions)
+        return {"k": write(cache["k"], kq), "ks": write(cache["ks"], ks),
+                "v": write(cache["v"], vq), "vs": write(cache["vs"], vs)}
 
 
 def decode_attention_q(q, cache, positions):
@@ -423,58 +431,52 @@ def init_mla(key, cfg):
 
 def mla(p, x, cfg, positions, cache=None, cache_pos=None, paged=None):
     """Latent attention; the cache stores only (c_kv, k_rope) — the paper's
-    BRAMAC quantization applies to every projection here as well."""
+    BRAMAC quantization applies to every projection here as well.  The
+    cache write is scoped `kv_write`, the rest `attention`, as in gqa."""
     B, S, _ = x.shape
     H = cfg.num_heads
     nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    q = dense(rmsnorm(p["q_norm"], dense(x, p["w_dq"], cfg.quant),
-                      cfg.norm_eps), p["w_uq"], cfg.quant)
-    q = q.reshape(B, S, H, nope + rope)
-    q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    with jax.named_scope("attention"):
+        q = dense(rmsnorm(p["q_norm"], dense(x, p["w_dq"], cfg.quant),
+                          cfg.norm_eps), p["w_uq"], cfg.quant)
+        q = q.reshape(B, S, H, nope + rope)
+        q_nope, q_rope = q[..., :nope], q[..., nope:]
+        q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
 
-    c_kv = rmsnorm(p["kv_norm"], dense(x, p["w_dkv"], cfg.quant), cfg.norm_eps)
-    k_rope = apply_rope(dense(x, p["w_kr"], cfg.quant)[:, :, None, :],
-                        positions, cfg.rope_theta)[:, :, 0]   # (B,S,rope)
+        c_kv = rmsnorm(p["kv_norm"], dense(x, p["w_dkv"], cfg.quant),
+                       cfg.norm_eps)
+        k_rope = apply_rope(dense(x, p["w_kr"], cfg.quant)[:, :, None, :],
+                            positions, cfg.rope_theta)[:, :, 0]  # (B,S,rope)
 
-    if cache is not None and isinstance(paged, DenseKV):
-        new_cache = {"c_kv": dense_update(cache["c_kv"], c_kv,
-                                          positions, paged),
-                     "k_rope": dense_update(cache["k_rope"], k_rope,
-                                            positions, paged)}
-        c_kv, k_rope = new_cache["c_kv"], new_cache["k_rope"]
+    new_cache = None
+    if cache is not None:
+        with jax.named_scope("kv_write"):
+            write = _row_writer(cache_pos, paged, positions)
+            new_cache = {"c_kv": write(cache["c_kv"], c_kv),
+                         "k_rope": write(cache["k_rope"], k_rope)}
+    with jax.named_scope("attention"):
+        if isinstance(paged, PagedKV) and cache is not None:
+            # up-projections run over the gathered view, exactly as the
+            # dense path runs them over the full (B, max_seq, …) cache
+            c_kv = paged_view(new_cache["c_kv"], paged)
+            k_rope = paged_view(new_cache["k_rope"], paged)
+        elif cache is not None:
+            c_kv, k_rope = new_cache["c_kv"], new_cache["k_rope"]
         Sk = c_kv.shape[1]
-    elif cache is not None and paged is not None:
-        new_cache = {"c_kv": paged_update(cache["c_kv"], c_kv,
-                                          positions, paged),
-                     "k_rope": paged_update(cache["k_rope"], k_rope,
-                                            positions, paged)}
-        # up-projections run over the gathered view, exactly as the dense
-        # path runs them over the full (B, max_seq, …) cache
-        c_kv = paged_view(new_cache["c_kv"], paged)
-        k_rope = paged_view(new_cache["k_rope"], paged)
-        Sk = c_kv.shape[1]
-    elif cache is not None:
-        c_kv = _update_cache(cache["c_kv"], c_kv, cache_pos)
-        k_rope = _update_cache(cache["k_rope"], k_rope, cache_pos)
-        new_cache = {"c_kv": c_kv, "k_rope": k_rope}
-        Sk = c_kv.shape[1]
-    else:
-        new_cache = None
-        Sk = S
 
-    k_nope = dense(c_kv, p["w_uk"], cfg.quant).reshape(B, Sk, H, nope)
-    v = dense(c_kv, p["w_uv"], cfg.quant).reshape(B, Sk, H, vd)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, Sk, H, rope))],
-        axis=-1)
-    q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k_nope = dense(c_kv, p["w_uk"], cfg.quant).reshape(B, Sk, H, nope)
+        v = dense(c_kv, p["w_uv"], cfg.quant).reshape(B, Sk, H, vd)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                      (B, Sk, H, rope))], axis=-1)
+        q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
 
-    if cache is not None:                        # decode or prefill chunk
-        out = chunk_attention(q_full, k, v, positions)
-    else:
-        out = causal_attention(q_full, k[:, :S], v[:, :S])
-    return dense(out.reshape(B, S, H * vd), p["wo"], cfg.quant), new_cache
+        if cache is not None:                    # decode or prefill chunk
+            out = chunk_attention(q_full, k, v, positions)
+        else:
+            out = causal_attention(q_full, k[:, :S], v[:, :S])
+        out = dense(out.reshape(B, S, H * vd), p["wo"], cfg.quant)
+    return out, new_cache
 
 
 def init_mla_cache(cfg, batch, max_seq, dtype, num_pages=None):
@@ -497,23 +499,25 @@ def xattn(p, x, cfg, vision_embeds, cache=None, cache_pos=None):
     """vision_embeds: (B, T_v, D) precomputed patch embeddings (stub
     frontend per the assignment).  K/V are position-free; for decode the
     projected K/V are cached once."""
-    B, S, _ = x.shape
-    H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
-    q = dense(x, p["wq"], cfg.quant).reshape(B, S, H, hd)
-    decoding = cache is not None and S == 1        # static condition
-    if decoding:
-        k, v = cache["k"], cache["v"]              # projected during prefill
-    else:
-        ve = rmsnorm(p["kv_norm"], vision_embeds, cfg.norm_eps)
-        Tv = ve.shape[1]
-        k = dense(ve, p["wk"], cfg.quant).reshape(B, Tv, Hkv, hd)
-        v = dense(ve, p["wv"], cfg.quant).reshape(B, Tv, Hkv, hd)
-    mask = jnp.ones((S, k.shape[1]), bool)
-    out = _attend(q, k, v, mask)
-    new_cache = {"k": k.astype(cache["k"].dtype),
-                 "v": v.astype(cache["v"].dtype)} \
-        if cache is not None else None
-    return dense(out.reshape(B, S, H * hd), p["wo"], cfg.quant), new_cache
+    with jax.named_scope("attention"):
+        B, S, _ = x.shape
+        H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
+        q = dense(x, p["wq"], cfg.quant).reshape(B, S, H, hd)
+        decoding = cache is not None and S == 1    # static condition
+        if decoding:
+            k, v = cache["k"], cache["v"]          # projected during prefill
+        else:
+            ve = rmsnorm(p["kv_norm"], vision_embeds, cfg.norm_eps)
+            Tv = ve.shape[1]
+            k = dense(ve, p["wk"], cfg.quant).reshape(B, Tv, Hkv, hd)
+            v = dense(ve, p["wv"], cfg.quant).reshape(B, Tv, Hkv, hd)
+        mask = jnp.ones((S, k.shape[1]), bool)
+        out = _attend(q, k, v, mask)
+        new_cache = {"k": k.astype(cache["k"].dtype),
+                     "v": v.astype(cache["v"].dtype)} \
+            if cache is not None else None
+        out = dense(out.reshape(B, S, H * hd), p["wo"], cfg.quant)
+    return out, new_cache
 
 
 def init_xattn_cache(cfg, batch, dtype):

@@ -56,6 +56,7 @@ def init_mlp(key, d_model, d_ff, dtype):
             "w_down": init_dense(k3, d_ff, d_model, dtype)}
 
 
+@jax.named_scope("mlp")
 def mlp(p, x, quant=None):
     g = dense(x, p["w_gate"], quant)
     u = dense(x, p["w_up"], quant)
@@ -97,6 +98,7 @@ def embed(p, tokens):
     return jnp.take(p["embedding"], tokens, axis=0)
 
 
+@jax.named_scope("unembed")
 def unembed(p, x, quant=None):
     return dense(x, p["unembed"], quant)
 

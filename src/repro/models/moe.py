@@ -58,6 +58,7 @@ def moe_capacity(T: int, E: int, k: int, capacity_factor: float) -> int:
     return int(max(1, round(T * k / E * capacity_factor)))
 
 
+@jax.named_scope("mlp")
 def moe(p, x, cfg, capacity_factor: float | None = None,
         dispatch: str | None = None):
     """x: (B, S, d) → (out, aux_loss).

@@ -78,6 +78,7 @@ def _filter_logits(logits, sc: SamplingConfig):
     return l
 
 
+@jax.named_scope("sample")
 def sample(logits, keys, sc: SamplingConfig):
     """logits (B, V), keys (B, 2) u32 -> (tokens (B,) i32, new_keys).
 
@@ -92,6 +93,7 @@ def sample(logits, keys, sc: SamplingConfig):
     return toks.astype(jnp.int32), new_keys
 
 
+@jax.named_scope("sample")
 def spec_verify(logits, drafts, keys, sc: SamplingConfig):
     """Vectorized accept/replace for one speculative draft window.
 

@@ -26,10 +26,12 @@ give the decode side its own mirror and slot registry.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import time
 
 import numpy as np
+from jax.profiler import annotate_function
 
 from repro.runtime import pages as pg
 from repro.runtime.options import RequestResult
@@ -207,6 +209,7 @@ class Scheduler:
     # admission policy
     # ------------------------------------------------------------------
 
+    @functools.partial(annotate_function, name="sched.plan_round")
     def plan_round(self) -> AdmissionRound | None:
         """Decide one admission round: FIFO over the queue into free
         admission slots, with the paged bookkeeping (prefix matching,

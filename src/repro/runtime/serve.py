@@ -105,6 +105,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.kernels import paged_attention as pk_kernel
 from repro.models import model as M
@@ -398,6 +399,9 @@ class Engine:
         # jit'd tick / per admission round, regardless of decode_steps
         self.n_ticks = 0
         self.n_admit_calls = 0
+        # prompt rows those admit calls computed (each pads to
+        # prefill_slots x prefill_chunk rows)
+        self.n_admit_rows = 0
         self.n_syncs = 0
         self.n_generated = 0
         # decode KV read accounting (kernels/paged_attention currency):
@@ -471,6 +475,7 @@ class Engine:
         else:
             self.state, self.caches = state, caches
 
+    @functools.partial(annotate_function, name="engine.admit")
     def _admit(self) -> None:
         paged = self.kv_layout == "paged"
         rnd = self.sched.plan_round()
@@ -479,14 +484,15 @@ class Engine:
         if not rnd.admitted:
             # eviction-only round: the registry already dropped its
             # chains host-side; commit the decrements on the device pool
-            st, ca = self._admit_side()
-            P = st.pages.refs.shape[0]
-            ev = np.zeros((P,), np.int32)
-            for p, d in rnd.evict_delta.items():
-                ev[p] = d
-            st = st._replace(pages=pg.apply_refs_delta(st.pages,
-                                                       jnp.asarray(ev)))
-            self._set_admit_side(st, ca)
+            with TraceAnnotation("engine.evict"):
+                st, ca = self._admit_side()
+                P = st.pages.refs.shape[0]
+                ev = np.zeros((P,), np.int32)
+                for p, d in rnd.evict_delta.items():
+                    ev[p] = d
+                st = st._replace(pages=pg.apply_refs_delta(st.pages,
+                                                           jnp.asarray(ev)))
+                self._set_admit_side(st, ca)
             if self.check_invariants:
                 self._verify_invariants()
             return
@@ -501,16 +507,19 @@ class Engine:
                                                self.sched.pool.pages_shared)
         self.prefill_chunks_skipped += rnd.chunks_skipped
         st, ca = self._admit_side()
-        st, ca, finals, n_calls = self.prefill.run_round(self.params, st,
-                                                         ca, rnd)
+        st, ca, finals, n_calls, n_rows = self.prefill.run_round(
+            self.params, st, ca, rnd)
         self._set_admit_side(st, ca)
         self.n_admit_calls += n_calls
+        self.n_admit_rows += n_rows
         # one blocking sync for the whole admission round
-        active = np.asarray(st.active)
+        with TraceAnnotation("engine.admit_sync"):
+            active = np.asarray(st.active)
+            first = {slot: int(np.asarray(finals[slot])[slot])
+                     for slot, _ in rnd.admitted}
         now = time.perf_counter()
         for slot, req in rnd.admitted:
-            tok = int(np.asarray(finals[slot])[slot])
-            req.out_tokens.append(tok)
+            req.out_tokens.append(first[slot])
             req.t_first = now
             self.n_generated += 1
             if not active[slot]:
@@ -525,6 +534,7 @@ class Engine:
         if self.check_invariants and paged:
             self._verify_invariants()
 
+    @functools.partial(annotate_function, name="engine.transfer")
     def _transfer(self) -> None:
         """Disagg: move every transferable prefilled request's pages
         into the decode pool (FIFO, backpressured by the scheduler)."""
@@ -694,7 +704,10 @@ class Engine:
         transferable prefilled requests into the decode pool, freeing
         prefill slots for this round), then `decode_steps` fused decode
         steps for all active decode slots (a single jit call and a
-        single host sync)."""
+        single host sync).  Each part is a profiler span on the host
+        (`engine.admit`, `engine.tick`, `engine.tick_sync`, `engine.emit`,
+        `engine.transfer`; see PERF.md for the spans and what reads
+        them)."""
         if self.disagg:
             self._transfer()
         self._admit()
@@ -715,42 +728,47 @@ class Engine:
         # kernel touches live pages only, the gather oracle — dense decode
         # and the speculative verify window included — always materializes
         # num_slots × max_seq rows)
-        if self.decode_kernel:
-            rows = pk_kernel.decode_read_rows(
-                [len(r.prompt) + len(r.out_tokens)
-                 for r in self.sched.decode_slot_req if r is not None],
-                self.page_size)
-        else:
-            rows = pk_kernel.oracle_read_rows(self.num_slots, self.max_seq)
-        self.kv_bytes_read += self.decode_steps * rows * self._kv_row_bytes
-        self.kv_read_steps += self.decode_steps
-        self.state, self.caches, toks, emitted = self.decode.tick(
-            self.params, self.state, self.caches)
+        with TraceAnnotation("engine.tick"):
+            if self.decode_kernel:
+                rows = pk_kernel.decode_read_rows(
+                    [len(r.prompt) + len(r.out_tokens)
+                     for r in self.sched.decode_slot_req if r is not None],
+                    self.page_size)
+            else:
+                rows = pk_kernel.oracle_read_rows(self.num_slots,
+                                                  self.max_seq)
+            self.kv_bytes_read += self.decode_steps * rows \
+                * self._kv_row_bytes
+            self.kv_read_steps += self.decode_steps
+            self.state, self.caches, toks, emitted = self.decode.tick(
+                self.params, self.state, self.caches)
+        with TraceAnnotation("engine.tick_sync"):
+            toks = np.asarray(toks)
+            emitted = np.asarray(emitted)
+            active = np.asarray(self.state.active)
+            if self.draft_len:
+                n_dr = np.asarray(self.state.n_drafted)
+                n_ac = np.asarray(self.state.n_accepted)
         # non-spec tick: (steps, slots); spec tick: (steps, slots, window)
         # — normalize to a trailing window axis of 1
-        toks = np.asarray(toks)
-        emitted = np.asarray(emitted)
         if toks.ndim == 2:
             toks, emitted = toks[..., None], emitted[..., None]
-        active = np.asarray(self.state.active)
-        if self.draft_len:
-            n_dr = np.asarray(self.state.n_drafted)
-            n_ac = np.asarray(self.state.n_accepted)
         self.n_ticks += 1
         self.n_syncs += 1
-        for slot, req in enumerate(self.sched.decode_slot_req):
-            if req is None:
-                continue
-            for t in range(toks.shape[0]):
-                for j in range(toks.shape[2]):
-                    if emitted[t, slot, j]:
-                        req.out_tokens.append(int(toks[t, slot, j]))
-                        self.n_generated += 1
-            if self.draft_len:
-                req.drafted_tokens = int(n_dr[slot])
-                req.accepted_tokens = int(n_ac[slot])
-            if not active[slot]:
-                self.sched.release_decode_slot(slot)
+        with TraceAnnotation("engine.emit"):
+            for slot, req in enumerate(self.sched.decode_slot_req):
+                if req is None:
+                    continue
+                for t in range(toks.shape[0]):
+                    for j in range(toks.shape[2]):
+                        if emitted[t, slot, j]:
+                            req.out_tokens.append(int(toks[t, slot, j]))
+                            self.n_generated += 1
+                if self.draft_len:
+                    req.drafted_tokens = int(n_dr[slot])
+                    req.accepted_tokens = int(n_ac[slot])
+                if not active[slot]:
+                    self.sched.release_decode_slot(slot)
         if self.check_invariants and self.kv_layout == "paged":
             self._verify_invariants()
         return True

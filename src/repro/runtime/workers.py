@@ -35,6 +35,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import attention as attn
 from repro.models import model as M
@@ -170,7 +171,9 @@ class DecodeWorker:
                 body, (state, caches), None, length=steps)
             if paged_mode:
                 dead = pre_active & ~state.active
-                state = state._replace(pages=pg.release(state.pages, dead))
+                with jax.named_scope("pages"):
+                    state = state._replace(pages=pg.release(state.pages,
+                                                            dead))
             return state, caches, toks, emitted
 
         return tick
@@ -260,7 +263,9 @@ class DecodeWorker:
                 body, (state, caches), None, length=steps)
             if paged_mode:
                 dead = pre_active & ~state.active
-                state = state._replace(pages=pg.release(state.pages, dead))
+                with jax.named_scope("pages"):
+                    state = state._replace(pages=pg.release(state.pages,
+                                                            dead))
             return state, caches, toks, emitted
 
         return tick
@@ -358,9 +363,10 @@ class PrefillWorker:
                   n_shared, new_pages, cow_src, evict_delta, register_delta):
             C = tokens.shape[1]
             if paged_mode:
-                pool = pg.admit_update(state.pages, admitting, shared,
-                                       n_shared, new_pages, evict_delta,
-                                       register_delta)
+                with jax.named_scope("pages"):
+                    pool = pg.admit_update(state.pages, admitting, shared,
+                                           n_shared, new_pages, evict_delta,
+                                           register_delta)
                 state = state._replace(pages=pool)
                 # copy-on-write split: a cached prefix that ends mid-page
                 # lands as a private copy in the slot's first FRESH page
@@ -371,10 +377,11 @@ class PrefillWorker:
                 # the source page's pre-call contents even if its chain
                 # was evicted and the page re-granted this same round.
                 mp = pool.tables.shape[1]
-                dst = jnp.take_along_axis(
-                    pool.tables, jnp.clip(n_shared, 0, mp - 1)[:, None],
-                    axis=1)[:, 0]
-                caches = pg.cow_copy(caches, pool_flags, cow_src, dst)
+                with jax.named_scope("pages"):
+                    dst = jnp.take_along_axis(
+                        pool.tables, jnp.clip(n_shared, 0, mp - 1)[:, None],
+                        axis=1)[:, 0]
+                    caches = pg.cow_copy(caches, pool_flags, cow_src, dst)
             # a slot's FIRST chunk starts from pristine state: recurrent
             # mixers accumulate (h/conv/C/n/m carry the previous occupant
             # forward — the seed engine's whole-prompt *_sequence prefill
@@ -456,7 +463,9 @@ class PrefillWorker:
                 # a request that terminates AT admission (first token EOS,
                 # or no decode room) must drop its references right here
                 dead = final & ~act
-                state = state._replace(pages=pg.release(state.pages, dead))
+                with jax.named_scope("pages"):
+                    state = state._replace(pages=pg.release(state.pages,
+                                                            dead))
             return state, caches, toks
 
         return admit
@@ -493,75 +502,81 @@ class PrefillWorker:
         """Execute a Scheduler AdmissionRound: build the per-chunk host
         arrays and drive the compiled admit over every chunk.  Returns
         the updated (state, caches), the per-slot final-chunk token
-        arrays (device-resident — the caller owns the sync) and the
-        number of compiled calls made."""
+        arrays (device-resident — the caller owns the sync), the number
+        of compiled calls made and the prompt rows they computed.  Each
+        call is a `prefill.call` span whose args are those rows and the
+        rows it pads to (slots x chunk)."""
         ns, C = self.num_slots, self.prefill_chunk
         paged = self.paged
         admitted, plan = rnd.admitted, rnd.plan
         starts, n_chunks = rnd.starts, rnd.n_chunks
         finals: dict[int, Any] = {}          # slot -> final-chunk tokens
         P = state.pages.refs.shape[0]
-        n_calls = 0
+        n_calls = n_rows = 0
         for ci in range(max(n_chunks.values())):
-            tokens = np.zeros((ns, C), np.int32)
-            valid = np.zeros((ns,), bool)
-            offsets = np.zeros((ns,), np.int32)
-            true_lens = np.ones((ns,), np.int32)
-            seeds = np.zeros((ns,), np.int32)
-            budgets0 = np.zeros((ns,), np.int32)
-            stops = np.full((ns, self.stop_cap), -1, np.int32)
-            admitting = np.zeros((ns,), bool)
-            shared = np.zeros((ns, self.pages_per_slot), np.int32)
-            n_shared = np.zeros((ns,), np.int32)
-            new_pages = np.zeros((ns,), np.int32)
-            cow_src = np.full((ns,), -1, np.int32)
-            ev_arr = np.zeros((P,), np.int32)
-            rg_arr = np.zeros((P,), np.int32)
-            if paged and ci == 0:
-                for p, d in rnd.evict_delta.items():
-                    ev_arr[p] = d
-                for p, d in rnd.reg_delta.items():
-                    rg_arr[p] = d
-            for slot, req in admitted:
-                if ci >= n_chunks[slot]:
-                    continue
-                off = starts[slot] + ci * C
+            rows = sum(min(C, len(req.prompt) - starts[slot] - ci * C)
+                       for slot, req in admitted if ci < n_chunks[slot])
+            with TraceAnnotation("prefill.call", rows=rows, padded=ns * C):
+                tokens = np.zeros((ns, C), np.int32)
+                valid = np.zeros((ns,), bool)
+                offsets = np.zeros((ns,), np.int32)
+                true_lens = np.ones((ns,), np.int32)
+                seeds = np.zeros((ns,), np.int32)
+                budgets0 = np.zeros((ns,), np.int32)
+                stops = np.full((ns, self.stop_cap), -1, np.int32)
+                admitting = np.zeros((ns,), bool)
+                shared = np.zeros((ns, self.pages_per_slot), np.int32)
+                n_shared = np.zeros((ns,), np.int32)
+                new_pages = np.zeros((ns,), np.int32)
+                cow_src = np.full((ns,), -1, np.int32)
+                ev_arr = np.zeros((P,), np.int32)
+                rg_arr = np.zeros((P,), np.int32)
                 if paged and ci == 0:
-                    m_len, full, cow, n_fresh = plan[slot]
-                    admitting[slot] = True
-                    shared[slot, :len(full)] = full
-                    n_shared[slot] = len(full)
-                    new_pages[slot] = n_fresh
-                    cow_src[slot] = cow
-                if ci == n_chunks[slot] - 1 and not paged:
-                    # dense only: a final chunk whose padded end would
-                    # cross max_seq slides back inside the cache
-                    # (dynamic_update_slice would clamp the write start and
-                    # scramble rows); the re-covered rows recompute to
-                    # identical values.  The paged scatter drops
-                    # out-of-range rows instead, so no slide is needed.
-                    off = min(off, max(0, self.max_seq - C))
-                piece = req.prompt[off:off + C]
-                tokens[slot, :len(piece)] = piece
-                valid[slot] = True
-                offsets[slot] = off
-                true_lens[slot] = len(req.prompt)
-                seeds[slot] = req.seed
-                budgets0[slot] = req.max_new_tokens - 1
-                stops[slot, :len(req.stop_tokens)] = req.stop_tokens
-            first = valid if ci == 0 else np.zeros((ns,), bool)
-            state, caches, toks = self._admit_chunk(
-                params, state, caches, jnp.asarray(tokens),
-                jnp.asarray(valid), jnp.asarray(first), jnp.asarray(offsets),
-                jnp.asarray(true_lens), jnp.asarray(seeds),
-                jnp.asarray(budgets0), jnp.asarray(stops),
-                jnp.asarray(admitting), jnp.asarray(shared),
-                jnp.asarray(n_shared), jnp.asarray(new_pages),
-                jnp.asarray(cow_src), jnp.asarray(ev_arr),
-                jnp.asarray(rg_arr))
+                    for p, d in rnd.evict_delta.items():
+                        ev_arr[p] = d
+                    for p, d in rnd.reg_delta.items():
+                        rg_arr[p] = d
+                for slot, req in admitted:
+                    if ci >= n_chunks[slot]:
+                        continue
+                    off = starts[slot] + ci * C
+                    if paged and ci == 0:
+                        m_len, full, cow, n_fresh = plan[slot]
+                        admitting[slot] = True
+                        shared[slot, :len(full)] = full
+                        n_shared[slot] = len(full)
+                        new_pages[slot] = n_fresh
+                        cow_src[slot] = cow
+                    if ci == n_chunks[slot] - 1 and not paged:
+                        # dense only: a final chunk whose padded end would
+                        # cross max_seq slides back inside the cache
+                        # (dynamic_update_slice would clamp the write start
+                        # and scramble rows); the re-covered rows recompute
+                        # to identical values.  The paged scatter drops
+                        # out-of-range rows instead, so no slide is needed.
+                        off = min(off, max(0, self.max_seq - C))
+                    piece = req.prompt[off:off + C]
+                    tokens[slot, :len(piece)] = piece
+                    valid[slot] = True
+                    offsets[slot] = off
+                    true_lens[slot] = len(req.prompt)
+                    seeds[slot] = req.seed
+                    budgets0[slot] = req.max_new_tokens - 1
+                    stops[slot, :len(req.stop_tokens)] = req.stop_tokens
+                first = valid if ci == 0 else np.zeros((ns,), bool)
+                state, caches, toks = self._admit_chunk(
+                    params, state, caches, jnp.asarray(tokens),
+                    jnp.asarray(valid), jnp.asarray(first),
+                    jnp.asarray(offsets), jnp.asarray(true_lens),
+                    jnp.asarray(seeds), jnp.asarray(budgets0),
+                    jnp.asarray(stops), jnp.asarray(admitting),
+                    jnp.asarray(shared), jnp.asarray(n_shared),
+                    jnp.asarray(new_pages), jnp.asarray(cow_src),
+                    jnp.asarray(ev_arr), jnp.asarray(rg_arr))
             n_calls += 1
+            n_rows += rows
             for slot, req in admitted:
                 if ci == n_chunks[slot] - 1:
                     finals[slot] = toks
             del toks
-        return state, caches, finals, n_calls
+        return state, caches, finals, n_calls, n_rows
