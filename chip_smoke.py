@@ -128,7 +128,8 @@ def phase_bramac(kind: str) -> None:
 
 def phase_paged(cfg, kind: str) -> None:
     """Both decode kernels at the engine's attention shapes: `cfg`'s
-    heads, `SLOTS` sequences, `MAX_SEQ` rows of `cfg.page_size` pages."""
+    heads, `SLOTS` sequences, `MAX_SEQ` rows of `cfg.page_size` pages,
+    read at layer 1 of pools stacked over two layers."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import paged_attention as pk
@@ -139,8 +140,11 @@ def phase_paged(cfg, kind: str) -> None:
     mp = MAX_SEQ // ps
     P = B * mp
     ks = jax.random.split(jax.random.PRNGKey(SEED), 4)
-    k_pool = jax.random.normal(ks[0], (P, ps, Hkv, hd)).astype(jnp.bfloat16)
-    v_pool = jax.random.normal(ks[1], (P, ps, Hkv, hd)).astype(jnp.bfloat16)
+    layer = 1
+    k_pool = jax.random.normal(ks[0], (2, P, ps, Hkv, hd)).astype(
+        jnp.bfloat16)
+    v_pool = jax.random.normal(ks[1], (2, P, ps, Hkv, hd)).astype(
+        jnp.bfloat16)
     q = jax.random.normal(ks[2], (B, 1, H, hd)).astype(jnp.bfloat16)
     tables = jax.random.permutation(ks[3], P).reshape(B, mp).astype(
         jnp.int32)
@@ -152,13 +156,14 @@ def phase_paged(cfg, kind: str) -> None:
     pos = (lengths - 1)[:, None]
     pv = A.PagedKV(tables=tables, n_pages=n_pages,
                    write_mask=jnp.ones((B,), bool), max_seq=MAX_SEQ,
-                   page_size=ps)
-    v_max = float(jnp.max(jnp.abs(v_pool.astype(jnp.float32))))
+                   page_size=ps, layer=layer)
+    v_max = float(jnp.max(jnp.abs(v_pool[layer].astype(jnp.float32))))
 
     got, cold = timed(lambda: pk.paged_decode(q[:, 0], k_pool, v_pool,
-                                              tables, n_pages, lengths))
+                                              tables, n_pages, lengths,
+                                              layer))
     _, warm = timed(lambda: pk.paged_decode(q[:, 0], k_pool, v_pool,
-                                            tables, n_pages, lengths))
+                                            tables, n_pages, lengths, layer))
     with jax.default_matmul_precision("highest"):
         f32 = jnp.float32
         want = A.chunk_attention(q.astype(f32),
@@ -186,7 +191,7 @@ def phase_paged(cfg, kind: str) -> None:
     qq, qs = A._quant_rows(q)
     got, cold = timed(lambda: pk.paged_decode_q(
         qq[:, 0], qs[:, 0], kq, kss, vq, vss, tables, n_pages, lengths,
-        q.dtype))
+        layer, q.dtype))
     cache = {"k": kq, "ks": kss, "v": vq, "vs": vss}
     view = {key: A.paged_view(cache[key], pv) for key in cache}
     want = A.decode_attention_q(q, view, pos)[:, 0]

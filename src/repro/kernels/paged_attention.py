@@ -7,7 +7,10 @@ even for ten-token sequences.  This kernel applies the paper's
 small-fixed-array discipline to the decode hot loop: each grid step owns one
 sequence, walks that sequence's block table directly, and DMAs one
 ``(page_size, Hkv, hd)`` K/V tile at a time from the pool into a fixed VMEM
-scratch buffer, combining pages with an online softmax.
+scratch buffer, combining pages with an online softmax.  The pool is the
+layer scan's stacked ``(n_periods, P, page_size, Hkv, hd)`` buffer, read at
+``[layer, page]`` with ``layer`` a scalar-prefetch operand, so no layer of
+it is ever sliced out (copied) before the kernel runs.
 
 Kernel invariants (the contract the parity suite pins):
 
@@ -53,7 +56,8 @@ from repro.kernels.ops import _interpret
 
 def _row(b, *_scalars):
     """Block index of sequence b's (1, ...) row; the scalar-prefetch refs
-    (tables, n_pages, lengths) that Pallas also passes are unused here."""
+    (tables, n_pages, lengths, layer) that Pallas also passes are unused
+    here."""
     return b, 0, 0
 
 
@@ -77,19 +81,20 @@ def _finish(out_ref, carry, H, hd):
     out_ref[0] = (acc / l[..., None]).reshape(H, hd).astype(out_ref.dtype)
 
 
-def _fp_kernel(tables_ref, n_ref, len_ref, q_ref, k_hbm, v_hbm, out_ref,
-               k_scr, v_scr, sems, *, page_size, hkv):
+def _fp_kernel(tables_ref, n_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
+               out_ref, k_scr, v_scr, sems, *, page_size, hkv):
     H, hd = q_ref.shape[1], q_ref.shape[2]
     g, ps = H // hkv, page_size
     b = pl.program_id(0)
+    layer = layer_ref[0]
     q = q_ref[0].astype(jnp.float32).reshape(hkv, g, hd)
     L = len_ref[b]
     n_eff = jnp.minimum(n_ref[b], (L + ps - 1) // ps)
 
     def body(j, carry):
         pid = tables_ref[b, j]
-        ck = pltpu.make_async_copy(k_hbm.at[pid], k_scr, sems.at[0])
-        cv = pltpu.make_async_copy(v_hbm.at[pid], v_scr, sems.at[1])
+        ck = pltpu.make_async_copy(k_hbm.at[layer, pid], k_scr, sems.at[0])
+        cv = pltpu.make_async_copy(v_hbm.at[layer, pid], v_scr, sems.at[1])
         ck.start()
         cv.start()
         ck.wait()
@@ -110,9 +115,9 @@ def _fp_kernel(tables_ref, n_ref, len_ref, q_ref, k_hbm, v_hbm, out_ref,
     _finish(out_ref, carry, H, hd)
 
 
-def _q_kernel(tables_ref, n_ref, len_ref, q_ref, qs_ref, k_hbm, ks_hbm,
-              v_hbm, vs_hbm, out_ref, k_scr, ks_scr, v_scr, vs_scr, sems,
-              *, page_size, hkv):
+def _q_kernel(tables_ref, n_ref, len_ref, layer_ref, q_ref, qs_ref, k_hbm,
+              ks_hbm, v_hbm, vs_hbm, out_ref, k_scr, ks_scr, v_scr, vs_scr,
+              sems, *, page_size, hkv):
     """int8 variant: reproduces `decode_attention_q`'s arithmetic — int8
     score dot with the K row scales factored out, fp32 softmax, V row
     scales folded into the probabilities, probabilities *requantized* to
@@ -133,6 +138,7 @@ def _q_kernel(tables_ref, n_ref, len_ref, q_ref, qs_ref, k_hbm, ks_hbm,
     g, ps = H // hkv, page_size
     w = ps * hkv
     b = pl.program_id(0)
+    layer = layer_ref[0]
     q = q_ref[0].astype(jnp.float32)                       # (H, hd) ints
     qs = qs_ref[0]                                         # (H, 1)
     L = len_ref[b]
@@ -145,10 +151,10 @@ def _q_kernel(tables_ref, n_ref, len_ref, q_ref, qs_ref, k_hbm, ks_hbm,
         """DMA page j's tiles; masked fp32 scores (H, w) exactly as the
         oracle computes them, plus the V tile and its row scales."""
         pid = tables_ref[b, j]
-        cps = [pltpu.make_async_copy(src.at[pid], dst, sems.at[i])
+        cps = [pltpu.make_async_copy(src, dst, sems.at[i])
                for i, (src, dst) in enumerate(
-                   ((k_hbm, k_scr), (ks_hbm, ks_scr),
-                    (v_hbm, v_scr), (vs_hbm, vs_scr)))]
+                   ((k_hbm.at[layer, pid], k_scr), (ks_hbm.at[pid], ks_scr),
+                    (v_hbm.at[layer, pid], v_scr), (vs_hbm.at[pid], vs_scr)))]
         for c in cps:
             c.start()
         for c in cps:
@@ -194,26 +200,34 @@ def _q_kernel(tables_ref, n_ref, len_ref, q_ref, qs_ref, k_hbm, ks_hbm,
     out_ref[0] = (acc.astype(jnp.float32) * pscale).astype(out_ref.dtype)
 
 
-def paged_decode(q, k_pool, v_pool, tables, n_pages, lengths):
-    """Decode attention straight off the paged pool (fp K/V).
+def _layer_arg(layer):
+    """The layer index as the (1,) i32 scalar-prefetch operand."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
 
-    q: (B, H, hd) roped queries; pools: (P, page_size, Hkv, hd);
-    tables: (B, max_pages) i32; n_pages: (B,) i32; lengths: (B,) i32 rows
-    each query attends (``position + 1``).  Returns (B, H, hd) in q.dtype.
+
+def paged_decode(q, k_pool, v_pool, tables, n_pages, lengths, layer):
+    """Decode attention straight off layer `layer` of the stacked paged
+    pool (fp K/V).
+
+    q: (B, H, hd) roped queries; pools: (n_periods, P, page_size, Hkv,
+    hd); tables: (B, max_pages) i32; n_pages: (B,) i32; lengths: (B,)
+    i32 rows each query attends (``position + 1``); layer: scalar i32.
+    Returns (B, H, hd) in q.dtype.
     """
     return _paged_decode(q, k_pool, v_pool, tables, n_pages, lengths,
-                         interpret=_interpret())
+                         _layer_arg(layer), interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_decode(q, k_pool, v_pool, tables, n_pages, lengths, *, interpret):
+def _paged_decode(q, k_pool, v_pool, tables, n_pages, lengths, layer, *,
+                  interpret):
     B, H, hd = q.shape
-    ps, Hkv = k_pool.shape[1], k_pool.shape[2]
+    ps, Hkv = k_pool.shape[2], k_pool.shape[3]
     kern = functools.partial(_fp_kernel, page_size=ps, hkv=Hkv)
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B,),
             in_specs=[pl.BlockSpec((1, H, hd), _row),
                       pl.BlockSpec(memory_space=pltpu.HBM),
@@ -226,40 +240,43 @@ def _paged_decode(q, k_pool, v_pool, tables, n_pages, lengths, *, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tables, n_pages, lengths, q, k_pool, v_pool)
+    )(tables, n_pages, lengths, layer, q, k_pool, v_pool)
 
 
 def paged_decode_q(q_int8, q_scale, k_pool, k_scales, v_pool, v_scales,
-                   tables, n_pages, lengths, out_dtype):
-    """int8-KV decode attention off the quantized pool.
+                   tables, n_pages, lengths, layer, out_dtype):
+    """int8-KV decode attention off layer `layer` of the stacked
+    quantized pool.
 
     q_int8/q_scale: (B, H, hd) int8 + (B, H) f32 row-quantized queries
     (callers quantize with `attention._quant_rows`, exactly as the oracle
-    does); k/v pools: (P, page_size, Hkv, hd) int8 with (P, page_size, Hkv)
-    f32 row scales.  Tolerance note vs `decode_attention_q`: the kernel
-    replays the oracle's arithmetic step for step, including the int8
-    probability requantization before the PV dot (see `_q_kernel`); the
-    only divergence left is the softmax normalizer's float association
-    order (summed per page here, once over max_seq there), so outputs
-    agree to reassociation error and greedy token streams stay identical
-    (asserted in the parity suite)."""
+    does); k/v pools: (n_periods, P, page_size, Hkv, hd) int8 with
+    (n_periods, P, page_size, Hkv) f32 row scales.  Tolerance note vs
+    `decode_attention_q`: the kernel replays the oracle's arithmetic step
+    for step, including the int8 probability requantization before the PV
+    dot (see `_q_kernel`); the only divergence left is the softmax
+    normalizer's float association order (summed per page here, once
+    over max_seq there), so outputs agree to reassociation error and
+    greedy token streams stay identical (asserted in the parity suite)."""
     return _paged_decode_q(q_int8, q_scale, k_pool, k_scales, v_pool,
                            v_scales, tables, n_pages, lengths,
+                           _layer_arg(layer),
                            out_dtype=jnp.dtype(out_dtype).name,
                            interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
 def _paged_decode_q(q_int8, q_scale, k_pool, k_scales, v_pool, v_scales,
-                    tables, n_pages, lengths, *, out_dtype, interpret):
+                    tables, n_pages, lengths, layer, *, out_dtype,
+                    interpret):
     B, H, hd = q_int8.shape
-    ps, Hkv = k_pool.shape[1], k_pool.shape[2]
+    ps, Hkv = k_pool.shape[2], k_pool.shape[3]
     kern = functools.partial(_q_kernel, page_size=ps, hkv=Hkv)
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B,),
             in_specs=[pl.BlockSpec((1, H, hd), _row),
                       pl.BlockSpec((1, H, 1), _row),
@@ -274,17 +291,21 @@ def _paged_decode_q(q_int8, q_scale, k_pool, k_scales, v_pool, v_scales,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tables, n_pages, lengths, q_int8, q_scale[..., None],
-      k_pool, _lane_rows(k_scales), v_pool, _lane_rows(v_scales))
+    )(tables, n_pages, lengths, layer, q_int8, q_scale[..., None],
+      k_pool, _lane_rows(k_scales, layer), v_pool,
+      _lane_rows(v_scales, layer))
 
 
-def _lane_rows(scales):
-    """(P, page_size, Hkv) row scales -> (P, 1, page_size * Hkv): one
-    lane-dense row per page, which the kernel can DMA (a (page_size, Hkv)
-    tile is narrower than the 128-lane tiling a DMA slice must respect).
-    The reshape is a relayout copy of the scale pool on every call."""
-    P, ps, hkv = scales.shape
-    return scales.reshape(P, 1, ps * hkv)
+def _lane_rows(scales, layer):
+    """Layer `layer` of the (n_periods, P, page_size, Hkv) row scales ->
+    (P, 1, page_size * Hkv): one lane-dense row per page, which the kernel
+    can DMA (a (page_size, Hkv) tile is narrower than the 128-lane tiling
+    a DMA slice must respect).  The reshape is a relayout copy of one
+    layer's scale pool on every call; taking the layer first keeps it to
+    that layer instead of the whole stack."""
+    _, P, ps, hkv = scales.shape
+    return jax.lax.dynamic_index_in_dim(scales, layer[0], keepdims=False) \
+        .reshape(P, 1, ps * hkv)
 
 
 # ---------------------------------------------------------------------------

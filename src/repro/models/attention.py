@@ -9,9 +9,13 @@ All support three modes driven by the call:
 Caches are plain dicts of arrays so they shard/checkpoint like params.
 
 KV layouts (the paper's small-fixed-array memory discipline applied to
-serving):
-  * dense — per-sequence (B, max_seq, …) reservations, updated with
-    dynamic_update_slice at cache_pos.
+serving).  A cache leaf holds every scanned layer: its axis 0 is the
+layer (period) index, and the mixers read and write it at the layer
+they are given (`layer`, which the layer scan also stamps into the
+`PagedKV`/`DenseKV` bundle), so the scan carries one set of buffers and
+never slices a layer out or restacks one:
+  * dense — per-sequence (B, max_seq, …) reservations, written at
+    cache_pos (the start clamped as dynamic_update_slice clamps it).
   * paged — one shared pool of (page_size,)-row pages per layer plus
     per-sequence int32 block tables (a `PagedKV` bundle threaded through
     the forward call).  Writes scatter rows through the table (masked
@@ -71,6 +75,9 @@ class PagedKV:
                dense (B, max_seq, …) view.  Writes, mla and Sq>1 chunks
                (prefill, the speculative verify window) always use the
                gather oracle, which stays the parity reference.
+    layer      i32 scalar (or int) — the layer of the stacked pool
+               (n_periods, P, page_size, …) this call writes and reads;
+               the layer scan sets it per layer (`dataclasses.replace`).
     """
     tables: jax.Array
     n_pages: jax.Array
@@ -80,6 +87,7 @@ class PagedKV:
     owned: jax.Array | None = None
     bound: jax.Array | None = None
     decode_kernel: bool = False
+    layer: jax.Array | int = 0
 
 
 @dataclasses.dataclass
@@ -92,15 +100,18 @@ class DenseKV:
     dense writes go through a per-position scatter that *drops*
     out-of-range rows instead, mirroring `paged_update`'s masking
     (write_mask gates whole sequences; `bound` is the same per-sequence
-    accepted-length bound PagedKV carries)."""
+    accepted-length bound PagedKV carries; `layer` is the layer of the
+    stacked cache written, as in PagedKV)."""
     write_mask: jax.Array                       # (B,) bool
     max_seq: int
     bound: jax.Array | None = None              # (B,) i32
+    layer: jax.Array | int = 0
 
 
 def dense_update(cache, new, positions, dv: DenseKV):
-    """Scatter `new` (B, S, …) rows into the dense cache (B, max_seq, …)
-    at absolute `positions` (B, S); masked / out-of-range rows drop.
+    """Scatter `new` (B, S, …) rows into layer `dv.layer` of the stacked
+    dense cache (n_periods, B, max_seq, …) at absolute `positions`
+    (B, S); masked / out-of-range rows drop.
 
     Both bounds matter: a negative position would wrap (`.at[]` follows
     NumPy indexing) and silently alias the tail of a live sequence."""
@@ -109,12 +120,14 @@ def dense_update(cache, new, positions, dv: DenseKV):
         ok &= positions < dv.bound[:, None]
     pos = jnp.where(ok, positions, dv.max_seq)  # max_seq is OOB -> dropped
     rows = jnp.arange(positions.shape[0])[:, None]
-    return cache.at[rows, pos].set(new.astype(cache.dtype), mode="drop")
+    return cache.at[dv.layer, rows, pos].set(new.astype(cache.dtype),
+                                             mode="drop")
 
 
 def paged_update(pool, new, positions, pv: PagedKV):
     """Scatter `new` (B, S, …) rows at absolute `positions` (B, S) through
-    the block table into `pool` ((P, page_size, …)).  Masked / out-of-range
+    the block table into layer `pv.layer` of the stacked pool
+    ((n_periods, P, page_size, …)), in place.  Masked / out-of-range
     rows — and rows aimed at a shared (un-owned) page or past the
     speculative bound — are routed to page id P and dropped.
 
@@ -122,7 +135,7 @@ def paged_update(pool, new, positions, pv: PagedKV):
     negative pg_idx (which passes `< n_pages`), clips to table entry 0, and
     `% page_size` wraps its row positive — without `positions >= 0` a stray
     padding row would land inside a live page."""
-    P, ps = pool.shape[0], pv.page_size
+    P, ps = pool.shape[1], pv.page_size
     pg_idx = positions // ps
     ok = pv.write_mask[:, None] & (pg_idx < pv.n_pages[:, None]) \
         & (positions < pv.max_seq) & (positions >= 0)
@@ -134,20 +147,22 @@ def paged_update(pool, new, positions, pv: PagedKV):
     pg = jnp.take_along_axis(
         pv.tables, jnp.clip(pg_idx, 0, pv.tables.shape[1] - 1), axis=1)
     pg = jnp.where(ok, pg, P)                       # OOB page id -> dropped
-    return pool.at[pg, positions % ps].set(new.astype(pool.dtype),
-                                           mode="drop")
+    return pool.at[pv.layer, pg, positions % ps].set(
+        new.astype(pool.dtype), mode="drop")
 
 
 def paged_view(pool, pv: PagedKV):
-    """Gather each sequence's pages into a dense (B, max_seq, …) view.
+    """Gather each sequence's pages of layer `pv.layer` of the stacked pool
+    into a dense (B, max_seq, …) view, in one gather (no layer of the
+    pool is sliced out first).
 
     Unallocated table entries gather garbage rows, but every such row sits
     at a position the causal mask excludes, where `_attend` replaces its
     score with exactly -1e30 — identical to the dense layout's untouched
     rows, so downstream softmax arithmetic is bit-identical."""
-    view = pool[jnp.clip(pv.tables, 0, pool.shape[0] - 1)]
+    view = pool[pv.layer, jnp.clip(pv.tables, 0, pool.shape[1] - 1)]
     B = pv.tables.shape[0]
-    view = view.reshape((B, -1) + pool.shape[2:])
+    view = view.reshape((B, -1) + pool.shape[3:])
     return view[:, :pv.max_seq]
 
 
@@ -238,9 +253,12 @@ def init_gqa(key, cfg):
             "wo": init_dense(ks[3], H * hd, d, dt)}
 
 
-def gqa(p, x, cfg, positions, cache=None, cache_pos=None, paged=None):
-    """cache: {"k","v"} (B, S_max, Hkv, hd), or (P, page_size, Hkv, hd)
-    pools when a `PagedKV` bundle is passed, or None (train/prefill).
+def gqa(p, x, cfg, positions, cache=None, cache_pos=None, paged=None,
+        layer=None):
+    """cache: {"k","v"} stacked over the scanned layers, (n_periods, B,
+    S_max, Hkv, hd), or (n_periods, P, page_size, Hkv, hd) pools when a
+    `PagedKV` bundle is passed, or None (train/prefill).  Only layer
+    `layer` is written and read; the whole stacked cache comes back.
 
     The cache write is scoped `kv_write` beside, not inside, the
     `attention` scope of the projections and the read, so a trace
@@ -258,10 +276,11 @@ def gqa(p, x, cfg, positions, cache=None, cache_pos=None, paged=None):
     if cache is None:
         new_cache = None
     elif "ks" in cache:                          # int8 KV cache (quant_kv)
-        new_cache = _update_cache_q(cache, k, v, cache_pos, paged, positions)
+        new_cache = _update_cache_q(cache, k, v, cache_pos, paged, positions,
+                                    layer)
     else:
         with jax.named_scope("kv_write"):
-            write = _row_writer(cache_pos, paged, positions)
+            write = _row_writer(cache_pos, paged, positions, layer)
             new_cache = {"k": write(cache["k"], k), "v": write(cache["v"], v)}
     with jax.named_scope("attention"):
         kernel = isinstance(paged, PagedKV) and paged.decode_kernel \
@@ -280,53 +299,61 @@ def gqa(p, x, cfg, positions, cache=None, cache_pos=None, paged=None):
                 out = pk.paged_decode_q(
                     qq[:, 0], qs[:, 0], new_cache["k"], new_cache["ks"],
                     new_cache["v"], new_cache["vs"], paged.tables,
-                    paged.n_pages, positions[:, 0] + 1, q.dtype)[:, None]
+                    paged.n_pages, positions[:, 0] + 1, paged.layer,
+                    q.dtype)[:, None]
             else:
-                view = new_cache if not isinstance(paged, PagedKV) else \
-                    {key: paged_view(new_cache[key], paged)
-                     for key in new_cache}
-                out = decode_attention_q(q, view, positions)
+                out = decode_attention_q(
+                    q, {key: _layer_view(new_cache[key], paged, layer)
+                        for key in new_cache}, positions)
         elif kernel:
             # page-bounded pallas decode kernel; the gather below stays
             # the parity oracle (and the Sq>1 prefill/verify path)
             out = pk.paged_decode(q[:, 0], new_cache["k"], new_cache["v"],
                                   paged.tables, paged.n_pages,
-                                  positions[:, 0] + 1)[:, None]
-        elif isinstance(paged, PagedKV):
-            out = chunk_attention(q, paged_view(new_cache["k"], paged),
-                                  paged_view(new_cache["v"], paged),
-                                  positions)
+                                  positions[:, 0] + 1,
+                                  paged.layer)[:, None]
         else:
-            # dense decode (S=1, positions == cache_pos), prefill chunks
-            # and the speculative dense window share the same masked path
-            # over the cache
-            out = chunk_attention(q, new_cache["k"], new_cache["v"],
+            # paged and dense decode (S=1, positions == cache_pos),
+            # prefill chunks and the speculative dense window share the
+            # same masked path over the layer's (B, max_seq, …) rows
+            out = chunk_attention(q, _layer_view(new_cache["k"], paged, layer),
+                                  _layer_view(new_cache["v"], paged, layer),
                                   positions)
         out = dense(out.reshape(B, S, H * hd), p["wo"], cfg.quant)
     return out, new_cache
 
 
-def _row_writer(pos, paged, positions):
-    """The cache write of one layout: a per-position scatter with drop
-    for the speculative dense window (`DenseKV`) and the paged pool, a
-    per-sequence dynamic_update_slice at `pos` for the dense cache."""
+def _row_writer(pos, paged, positions, layer):
+    """The cache write of one layout into one layer of a stacked leaf: a
+    per-position scatter with drop for the speculative dense window
+    (`DenseKV`) and the paged pool, at the bundle's layer; a per-sequence
+    contiguous write at `pos` and `layer` for the dense cache."""
     if isinstance(paged, DenseKV):
         return lambda c, n: dense_update(c, n, positions, paged)
     if paged is not None:
         return lambda c, n: paged_update(c, n, positions, paged)
-    return lambda c, n: _update_cache(c, n, pos)
+    return lambda c, n: _update_cache(c, n, pos, layer)
 
 
-def _update_cache(cache, new, pos):
-    """Insert `new` (B,S,…) at per-batch position `pos` (B,)."""
+def _layer_view(cache, paged, layer):
+    """One layer's (B, max_seq, …) rows of a stacked cache leaf: the
+    paged pool's gather through the block table (at the bundle's layer),
+    or the dense slice at `layer`."""
+    if isinstance(paged, PagedKV):
+        return paged_view(cache, paged)
+    return cache[layer]
+
+
+def _update_cache(cache, new, pos, layer):
+    """Insert `new` (B,S,…) into layer `layer` of the stacked cache
+    (n_periods, B, max_seq, …) at per-batch position `pos` (B,).  The
+    start is clamped to [0, max_seq - S], exactly as a per-sequence
+    dynamic_update_slice clamps it (S == max_seq overwrites every row)."""
     B, S = new.shape[:2]
-    if S == cache.shape[1]:
-        return new.astype(cache.dtype)
-
-    def upd(c, n, p):
-        return jax.lax.dynamic_update_slice(
-            c, n.astype(c.dtype), (p,) + (0,) * (c.ndim - 1))
-    return jax.vmap(upd)(cache, new, pos)
+    start = jnp.clip(pos, 0, cache.shape[2] - S)
+    rows = start[:, None] + jnp.arange(S, dtype=start.dtype)[None]
+    return cache.at[layer, jnp.arange(B)[:, None], rows].set(
+        new.astype(cache.dtype))
 
 
 def init_gqa_cache(cfg, batch, max_seq, dtype, num_pages=None):
@@ -362,11 +389,11 @@ def _quant_rows(x):
     return jnp.clip(q, -127, 127).astype(jnp.int8), scale
 
 
-def _update_cache_q(cache, k, v, pos, paged=None, positions=None):
+def _update_cache_q(cache, k, v, pos, paged, positions, layer):
     with jax.named_scope("kv_write"):
         kq, ks = _quant_rows(k)
         vq, vs = _quant_rows(v)
-        write = _row_writer(pos, paged, positions)
+        write = _row_writer(pos, paged, positions, layer)
         return {"k": write(cache["k"], kq), "ks": write(cache["ks"], ks),
                 "v": write(cache["v"], vq), "vs": write(cache["vs"], vs)}
 
@@ -429,10 +456,12 @@ def init_mla(key, cfg):
     }
 
 
-def mla(p, x, cfg, positions, cache=None, cache_pos=None, paged=None):
+def mla(p, x, cfg, positions, cache=None, cache_pos=None, paged=None,
+        layer=None):
     """Latent attention; the cache stores only (c_kv, k_rope) — the paper's
     BRAMAC quantization applies to every projection here as well.  The
-    cache write is scoped `kv_write`, the rest `attention`, as in gqa."""
+    stacked cache is written and read at `layer`; the write is scoped
+    `kv_write`, the rest `attention`, as in gqa."""
     B, S, _ = x.shape
     H = cfg.num_heads
     nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
@@ -451,17 +480,15 @@ def mla(p, x, cfg, positions, cache=None, cache_pos=None, paged=None):
     new_cache = None
     if cache is not None:
         with jax.named_scope("kv_write"):
-            write = _row_writer(cache_pos, paged, positions)
+            write = _row_writer(cache_pos, paged, positions, layer)
             new_cache = {"c_kv": write(cache["c_kv"], c_kv),
                          "k_rope": write(cache["k_rope"], k_rope)}
     with jax.named_scope("attention"):
-        if isinstance(paged, PagedKV) and cache is not None:
-            # up-projections run over the gathered view, exactly as the
-            # dense path runs them over the full (B, max_seq, …) cache
-            c_kv = paged_view(new_cache["c_kv"], paged)
-            k_rope = paged_view(new_cache["k_rope"], paged)
-        elif cache is not None:
-            c_kv, k_rope = new_cache["c_kv"], new_cache["k_rope"]
+        if cache is not None:
+            # up-projections run over the layer's (B, max_seq, …) rows:
+            # the gathered view (paged) or the dense cache
+            c_kv = _layer_view(new_cache["c_kv"], paged, layer)
+            k_rope = _layer_view(new_cache["k_rope"], paged, layer)
         Sk = c_kv.shape[1]
 
         k_nope = dense(c_kv, p["w_uk"], cfg.quant).reshape(B, Sk, H, nope)
@@ -495,17 +522,20 @@ def init_xattn(key, cfg):
         "kv_norm": init_rmsnorm(cfg.d_model, cfg.compute_dtype)}
 
 
-def xattn(p, x, cfg, vision_embeds, cache=None, cache_pos=None):
+def xattn(p, x, cfg, vision_embeds, cache=None, cache_pos=None, layer=None):
     """vision_embeds: (B, T_v, D) precomputed patch embeddings (stub
     frontend per the assignment).  K/V are position-free; for decode the
-    projected K/V are cached once."""
+    projected K/V are cached once, at `layer` of the stacked cache
+    (n_periods, B, T_v, Hkv, hd), which decode reads and returns
+    unchanged."""
     with jax.named_scope("attention"):
         B, S, _ = x.shape
         H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
         q = dense(x, p["wq"], cfg.quant).reshape(B, S, H, hd)
         decoding = cache is not None and S == 1    # static condition
         if decoding:
-            k, v = cache["k"], cache["v"]          # projected during prefill
+            # projected during prefill
+            k, v = cache["k"][layer], cache["v"][layer]
         else:
             ve = rmsnorm(p["kv_norm"], vision_embeds, cfg.norm_eps)
             Tv = ve.shape[1]
@@ -513,9 +543,13 @@ def xattn(p, x, cfg, vision_embeds, cache=None, cache_pos=None):
             v = dense(ve, p["wv"], cfg.quant).reshape(B, Tv, Hkv, hd)
         mask = jnp.ones((S, k.shape[1]), bool)
         out = _attend(q, k, v, mask)
-        new_cache = {"k": k.astype(cache["k"].dtype),
-                     "v": v.astype(cache["v"].dtype)} \
-            if cache is not None else None
+        if cache is None or decoding:
+            new_cache = cache
+        else:
+            new_cache = {"k": cache["k"].at[layer].set(
+                             k.astype(cache["k"].dtype)),
+                         "v": cache["v"].at[layer].set(
+                             v.astype(cache["v"].dtype))}
         out = dense(out.reshape(B, S, H * hd), p["wo"], cfg.quant)
     return out, new_cache
 

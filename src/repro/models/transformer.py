@@ -8,6 +8,7 @@ the scan (overlappable by the XLA latency-hiding scheduler).
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any
 
 import jax
@@ -86,9 +87,20 @@ def init_block_cache(cfg, spec: str, batch: int, max_seq: int, dtype,
 # per-block apply
 # ---------------------------------------------------------------------------
 
+# recurrent mixer -> (one decode step, whole-sequence prefill)
+_RECURRENT = {"mamba": (mb.mamba_step, mb.mamba_sequence),
+              "mlstm": (xl.mlstm_step, xl.mlstm_sequence),
+              "slstm": (xl.slstm_step, xl.slstm_sequence)}
+
+
 def block_apply(p, x, cfg, spec, *, positions, vision_embeds=None,
-                cache=None, cache_pos=None, paged=None):
+                cache=None, cache_pos=None, paged=None, layer=None):
     """Returns (x, aux_loss, new_cache).
+
+    `cache` is this pattern position's cache stacked over the scanned
+    layers (axis 0); the block reads and writes it at `layer` only (the
+    `paged` bundle carries the same index) and returns the whole stacked
+    tree (None when `cache` is None: training).
 
     `paged` (an attention.PagedKV bundle, threaded untouched from the
     engine) selects the paged KV discipline inside gqa/mla — including,
@@ -96,31 +108,24 @@ def block_apply(p, x, cfg, spec, *, positions, vision_embeds=None,
     kernel for Sq=1 gqa reads (mla always takes the gather oracle)."""
     mixer, ff = parse_spec(spec)
     h = rmsnorm(p["norm1"], x, cfg.norm_eps)
-    decode = cache is not None and x.shape[1] == 1
     if mixer == "attn":
         y, new_cache = attn.gqa(p["mixer"], h, cfg, positions, cache,
-                                cache_pos, paged)
+                                cache_pos, paged, layer)
     elif mixer == "mla":
         y, new_cache = attn.mla(p["mixer"], h, cfg, positions, cache,
-                                cache_pos, paged)
+                                cache_pos, paged, layer)
     elif mixer == "xattn":
         y, new_cache = attn.xattn(p["mixer"], h, cfg, vision_embeds,
-                                  cache, cache_pos)
-    elif mixer == "mamba":
-        if decode:
-            y, new_cache = mb.mamba_step(p["mixer"], h, cfg, cache)
+                                  cache, cache_pos, layer)
+    else:
+        step, sequence = _RECURRENT[mixer]
+        if cache is not None and x.shape[1] == 1:      # decode
+            y, state = step(p["mixer"], h, cfg, jax.tree_util.tree_map(
+                lambda a: a[layer], cache))
         else:
-            y, new_cache = mb.mamba_sequence(p["mixer"], h, cfg)
-    elif mixer == "mlstm":
-        if decode:
-            y, new_cache = xl.mlstm_step(p["mixer"], h, cfg, cache)
-        else:
-            y, new_cache = xl.mlstm_sequence(p["mixer"], h, cfg)
-    elif mixer == "slstm":
-        if decode:
-            y, new_cache = xl.slstm_step(p["mixer"], h, cfg, cache)
-        else:
-            y, new_cache = xl.slstm_sequence(p["mixer"], h, cfg)
+            y, state = sequence(p["mixer"], h, cfg)
+        new_cache = None if cache is None else jax.tree_util.tree_map(
+            lambda a, s: a.at[layer].set(s.astype(a.dtype)), cache, state)
     x = x + y
     aux = jnp.zeros((), jnp.float32)
     if ff == "dense":
@@ -177,23 +182,31 @@ def stack_cache_pool_flags(cfg):
 
 def stack_apply(params, x, cfg, *, positions, vision_embeds=None,
                 caches=None, cache_pos=None, paged=None):
-    """Scan over periods. Returns (x, aux_total, new_caches)."""
+    """Scan over periods. Returns (x, aux_total, new_caches).
 
-    def period(x, layer_in):
-        p_slice, cache_slice = layer_in
+    The stacked caches ride in the scan's carry, not its xs/ys: every
+    block reads and writes them at its layer index, so the tree stays
+    one set of buffers that XLA updates in place.  As the scan's xs/ys,
+    XLA would slice every layer out, write it into a fresh output and
+    copy the whole tree back on every call (XLA cannot alias a scan's
+    input and output)."""
+
+    def period(x, caches, p_slice, layer):
         aux_total = jnp.zeros((), jnp.float32)
-        new_caches = {}
+        bundle = None if paged is None else dataclasses.replace(paged,
+                                                                layer=layer)
         for i, spec in enumerate(cfg.layer_pattern):
-            cache_i = None if cache_slice is None else cache_slice[f"pos{i}"]
+            key = f"pos{i}"
             x, aux, nc = block_apply(
-                p_slice[f"pos{i}"], x, cfg, spec, positions=positions,
-                vision_embeds=vision_embeds, cache=cache_i,
-                cache_pos=cache_pos, paged=paged)
+                p_slice[key], x, cfg, spec, positions=positions,
+                vision_embeds=vision_embeds,
+                cache=None if caches is None else caches[key],
+                cache_pos=cache_pos, paged=bundle, layer=layer)
             aux_total += aux
-            if nc is not None:
-                new_caches[f"pos{i}"] = nc
+            if caches is not None:
+                caches = {**caches, key: nc}
         x = constrain(x, "batch", "act_seq", None)
-        return x, (aux_total, new_caches if new_caches else None)
+        return x, aux_total, caches
 
     body = period
     if cfg.remat:
@@ -201,29 +214,21 @@ def stack_apply(params, x, cfg, *, positions, vision_embeds=None,
                               policy=jax.checkpoint_policies.nothing_saveable)
 
     def scan_body(carry, layer_in):
-        x, aux_acc = carry
-        x, (aux, new_caches) = body(x, layer_in)
-        return (x, aux_acc + aux), new_caches
+        x, aux_acc, caches = carry
+        x, aux, caches = body(x, caches, *layer_in)
+        return (x, aux_acc + aux, caches), None
 
-    xs = (params, caches)
+    carry = (x, jnp.zeros((), jnp.float32), caches)
     if cfg.scan_layers:
-        (x, aux), new_caches = jax.lax.scan(
-            scan_body, (x, jnp.zeros((), jnp.float32)), xs)
-        return x, aux, new_caches
+        carry, _ = jax.lax.scan(
+            scan_body, carry,
+            (params, jnp.arange(cfg.n_periods, dtype=jnp.int32)))
+        return carry
 
     # unrolled (python-loop) stack: identical computation, every period in
     # the HLO — used by the dry-run's cost probe (scan bodies are counted
     # once by XLA cost analysis) and available as a runtime choice.
-    carry = (x, jnp.zeros((), jnp.float32))
-    out_caches = []
     for i in range(cfg.n_periods):
-        layer_in = jax.tree_util.tree_map(lambda a: a[i], xs)
-        carry, nc = scan_body(carry, layer_in)
-        out_caches.append(nc)
-    (x, aux) = carry
-    if out_caches and out_caches[0] is not None:
-        new_caches = jax.tree_util.tree_map(
-            lambda *ls: jnp.stack(ls), *out_caches)
-    else:
-        new_caches = None
-    return x, aux, new_caches
+        carry, _ = scan_body(
+            carry, (jax.tree_util.tree_map(lambda a: a[i], params), i))
+    return carry

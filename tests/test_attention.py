@@ -66,12 +66,17 @@ def test_decode_attention_masks_future():
 
 
 def test_cache_update_at_position():
-    cache = jnp.zeros((2, 8, 2, 4))
+    """Rows land at each sequence's position in the addressed layer of the
+    stacked (n_periods, B, max_seq, ...) cache, and nowhere else."""
+    cache = jnp.zeros((3, 2, 8, 2, 4))
     new = jnp.ones((2, 1, 2, 4))
     pos = jnp.array([2, 5], jnp.int32)
-    out = A._update_cache(cache, new, pos)
-    assert float(out[0, 2].sum()) == 8.0 and float(out[1, 5].sum()) == 8.0
-    assert float(out[0, 5].sum()) == 0.0 and float(out[1, 2].sum()) == 0.0
+    out = A._update_cache(cache, new, pos, 1)
+    assert float(out[1, 0, 2].sum()) == 8.0
+    assert float(out[1, 1, 5].sum()) == 8.0
+    assert float(out[1, 0, 5].sum()) == 0.0
+    assert float(out[1, 1, 2].sum()) == 0.0
+    assert float(out[0].sum()) == 0.0 and float(out[2].sum()) == 0.0
 
 
 def test_int8_kv_cache_decode_close_to_fp():
@@ -144,12 +149,13 @@ def test_negative_position_never_writes_dense():
     """Regression: a padding row at position -1 used to wrap through
     numpy-style negative indexing into the cache's last row; the write
     masks must drop it."""
-    cache = jnp.zeros((2, 8, 2, 4))
+    cache = jnp.zeros((2, 2, 8, 2, 4))              # (n_periods, B, ...)
     new = jnp.ones((2, 1, 2, 4))
-    dv = A.DenseKV(write_mask=jnp.ones((2,), bool), max_seq=8)
+    dv = A.DenseKV(write_mask=jnp.ones((2,), bool), max_seq=8, layer=1)
     out = A.dense_update(cache, new, jnp.array([[-1], [3]], jnp.int32), dv)
-    assert float(jnp.abs(out[0]).sum()) == 0.0      # -1 must not alias row 7
-    assert float(jnp.abs(out[1, 3]).sum()) == 8.0   # in-range row still lands
+    assert float(jnp.abs(out[1, 0]).sum()) == 0.0   # -1 must not alias row 7
+    assert float(jnp.abs(out[1, 1, 3]).sum()) == 8.0  # in-range row lands
+    assert float(jnp.abs(out[0]).sum()) == 0.0      # other layer untouched
 
 
 def test_negative_position_never_writes_paged():
@@ -157,7 +163,7 @@ def test_negative_position_never_writes_paged():
     `< n_pages`), clips to table entry 0 and wraps its row positive —
     without the lower bound it landed inside a live page."""
     P, ps = 4, 4
-    pool = jnp.zeros((P, ps, 2, 4))
+    pool = jnp.zeros((2, P, ps, 2, 4))              # (n_periods, P, ...)
     new = jnp.ones((2, 1, 2, 4))
     pv = A.PagedKV(tables=jnp.array([[1, 2], [3, 0]], jnp.int32),
                    n_pages=jnp.array([2, 2], jnp.int32),
@@ -165,7 +171,8 @@ def test_negative_position_never_writes_paged():
                    max_seq=8, page_size=ps)
     out = A.paged_update(pool, new, jnp.array([[-1], [5]], jnp.int32), pv)
     # slot 0's write at -1 must vanish: its pages (1 and 2) stay zero
-    assert float(jnp.abs(out[1]).sum()) == 0.0
-    assert float(jnp.abs(out[2]).sum()) == 0.0
+    assert float(jnp.abs(out[0, 1]).sum()) == 0.0
+    assert float(jnp.abs(out[0, 2]).sum()) == 0.0
     # slot 1's in-range write lands in page 0 (table entry 1), row 5%4
-    assert float(jnp.abs(out[0, 1]).sum()) == 8.0
+    assert float(jnp.abs(out[0, 0, 1]).sum()) == 8.0
+    assert float(jnp.abs(out[1]).sum()) == 0.0      # other layer untouched

@@ -5,6 +5,8 @@
   * capacity MoE dispatch == dense all-experts reference when no drops
   * sLSTM sequence == step-by-step decode
   * stack with scan_layers=True == unrolled stack
+  * stack with the caches carried and addressed by layer == a per-layer
+    reference that slices each layer's caches and restacks them
 """
 import jax
 import jax.numpy as jnp
@@ -15,7 +17,9 @@ from _hypothesis_compat import given, settings, strategies as st
 from repro.configs import get_config
 from repro.models import mamba as mb
 from repro.models import model as M
+from repro.models import attention as A
 from repro.models import moe as moe_mod
+from repro.models import transformer as tf
 from repro.models import xlstm as xl
 
 jax.config.update("jax_platform_name", "cpu")
@@ -194,3 +198,106 @@ def test_scan_equals_unrolled_stack(arch):
     np.testing.assert_allclose(np.asarray(a, np.float32),
                                np.asarray(b, np.float32),
                                rtol=2e-4, atol=2e-4)
+
+
+# case -> (arch, config changes, paged layout, decode kernel, chunk rows)
+CARRY_CASES = {
+    "paged-kernel": ("granite-8b", {"num_layers": 3}, True, True, 1),
+    "paged-gather": ("granite-8b", {"num_layers": 3}, True, False, 4),
+    "dense": ("granite-8b", {"num_layers": 3}, False, False, 4),
+    "int8-kv": ("granite-8b", {"num_layers": 3, "quant_kv": True}, True,
+                True, 1),
+    "mla": ("minicpm3-4b", {"num_layers": 3}, True, False, 4),
+    "recurrent-decode": ("jamba-1.5-large-398b", {"num_layers": 16}, True,
+                         True, 1),
+    "recurrent-chunk": ("xlstm-1.3b", {"num_layers": 16}, False, False, 4),
+}
+
+
+def _filled(caches, key):
+    """Every cache leaf filled with seeded values, so that a read of the
+    wrong layer or of stale rows shows in the output."""
+    leaves, tree = jax.tree_util.tree_flatten_with_path(caches)
+    keys = jax.random.split(key, len(leaves))
+    out = []
+    for k, (path, a) in zip(keys, leaves):
+        if a.dtype == jnp.int8:
+            out.append(jax.random.randint(k, a.shape, -127, 128, jnp.int32)
+                       .astype(jnp.int8))
+        elif getattr(path[-1], "key", None) in ("ks", "vs"):   # row scales
+            out.append(jax.random.uniform(k, a.shape, a.dtype, 1e-3, 2e-2))
+        else:
+            out.append((0.5 * jax.random.normal(k, a.shape)).astype(a.dtype))
+    return jax.tree_util.tree_unflatten(tree, out)
+
+
+def _per_layer_reference(params, x, cfg, caches, **kw):
+    """The layer loop as it was before the caches rode in the carry: slice
+    layer l out of every cache leaf (as a one-layer stack), run each
+    block of the period on it, and restack the layers at the end."""
+    aux_total = jnp.zeros((), jnp.float32)
+    layers = []
+    for l in range(cfg.n_periods):
+        p_l = jax.tree_util.tree_map(lambda a: a[l], params)
+        c_l = jax.tree_util.tree_map(lambda a: a[l:l + 1], caches)
+        for i, spec in enumerate(cfg.layer_pattern):
+            key = f"pos{i}"
+            x, aux, c_l[key] = tf.block_apply(
+                p_l[key], x, cfg, spec, cache=c_l[key], layer=0, **kw)
+            aux_total += aux
+        layers.append(c_l)
+    return x, aux_total, jax.tree_util.tree_map(
+        lambda *ls: jnp.concatenate(ls), *layers)
+
+
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "unrolled"])
+@pytest.mark.parametrize("case", sorted(CARRY_CASES))
+def test_carried_caches_equal_per_layer_reference(case, scan):
+    """stack_apply with the stacked caches in the layer loop's carry,
+    written and read at each layer's index, matches bit for bit a loop
+    that slices each layer's caches, runs block_apply on them and
+    restacks: hidden states, aux loss and every cache leaf.  Slots mix
+    write-masked, read-only (shared) and fresh pages, and positions
+    straddle page boundaries."""
+    arch, changes, paged, kernel, S = CARRY_CASES[case]
+    cfg = get_config(arch, smoke=True).replace(scan_layers=scan, **changes)
+    assert cfg.n_periods >= 2
+    B, max_seq, ps = 3, 64, cfg.page_size
+    key = jax.random.PRNGKey(7)
+    params = M.init_params(cfg, key)["layers"]
+    mp = max_seq // ps
+    P = B * mp + 2
+    caches = _filled(M.init_cache(cfg, B, max_seq,
+                                  num_pages=P if paged else None),
+                     jax.random.fold_in(key, 1))
+    x = jax.random.normal(jax.random.fold_in(key, 2), (B, S, cfg.d_model),
+                          cfg.compute_dtype)
+    cache_pos = jnp.asarray([5, 15, 31], jnp.int32)
+    positions = cache_pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
+    if paged:
+        tables = jax.random.permutation(jax.random.fold_in(key, 3), P)[
+            :B * mp].reshape(B, mp).astype(jnp.int32)
+        owned = jnp.ones((B, mp), bool).at[1, 0].set(False)
+        bundle = A.PagedKV(tables=tables,
+                           n_pages=jnp.asarray([1, 2, 3], jnp.int32),
+                           write_mask=jnp.asarray([True, True, False]),
+                           max_seq=max_seq, page_size=ps, owned=owned,
+                           decode_kernel=kernel)
+    else:
+        bundle = None
+    kw = dict(positions=positions, cache_pos=cache_pos, paged=bundle)
+
+    got = jax.jit(lambda p, x, c: tf.stack_apply(p, x, cfg, caches=c,
+                                                 **kw))(params, x, caches)
+    want = jax.jit(lambda p, x, c: _per_layer_reference(p, x, cfg, c, **kw))(
+        params, x, caches)
+    got_leaves = jax.tree_util.tree_leaves(got)
+    want_leaves = jax.tree_util.tree_leaves(want)
+    assert len(got_leaves) == len(want_leaves)
+    for g, w in zip(got_leaves, want_leaves):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    # the call wrote something: the caches it returns differ from its input
+    assert any(not np.array_equal(np.asarray(a), np.asarray(b))
+               for a, b in zip(jax.tree_util.tree_leaves(got[2]),
+                               jax.tree_util.tree_leaves(caches)))
