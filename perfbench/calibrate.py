@@ -51,7 +51,7 @@ def reading(cfg: dict, mix: dict, seed: int, seconds: float, counter,
     out = {"seed": seed, "sampled": len(sample), "serve_s": t1 - t0}
     if not sample:
         return out
-    S, Rn = correct.pads(mix)
+    S, Rn = correct.pads(mix, cfg)
     prog, ctl = correct.gaps(cfg, seed, sample, S, Rn,
                              CONTROL[cfg["weights"]] if control else None)
     out["reference_s"] = time.perf_counter() - t1

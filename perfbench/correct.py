@@ -1,8 +1,12 @@
 """What decides `correct`: served tokens against the plain reference.
 
 After the window a sample of finished requests, drawn from the seed and
-always holding the one with the most served tokens, is run through
-`reference.py` teacher-forced over prompt + served tokens.  For every
+always holding the one with the most served tokens, is run through the
+configuration's reference teacher-forced over prompt + served tokens.
+The reference is the module the file names under `"reference"` (a path
+from the checkout's root; `perfbench/reference.py`, the dense decoder,
+when it names none), loaded by path as the metric readers are; its
+contract is in `reference.py`'s docstring.  For every
 served token the reference's best logit at that position minus its
 logit for the served token is a gap (0 where the engine chose the
 reference's argmax); the number compared is the mean gap over every
@@ -13,27 +17,45 @@ widest gap does not (PERF.md, section 6).  Greedy serving is what makes
 this valid.
 
 `control` reads the same positions with the reference one precision
-below the configuration (`reference.logits_at(quant=...)`): the gap of the
-token the lower precision puts first.  Only calibration runs it.
+below the configuration (`logits_at(quant=...)`): the gap of the token
+the lower precision puts first.  Only calibration runs it.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 import pathlib
+import re
 
 import jax.numpy as jnp
 import numpy as np
 
-from perfbench import reference as R
-
 HERE = pathlib.Path(__file__).resolve().parent
+ROOT = HERE.parent
 GROUP = 4
+DEFAULT_REFERENCE = "perfbench/reference.py"
+_MODULES: dict = {}
 
 
-def pads(mix: dict) -> tuple[int, int]:
-    """(sequence length, scored rows) that hold any request of the mix."""
+def reference(cfg: dict):
+    """The reference module the configuration names, loaded once per
+    path."""
+    rel = cfg.get("reference", DEFAULT_REFERENCE)
+    if rel not in _MODULES:
+        spec = importlib.util.spec_from_file_location(
+            "perfbench_reference_" + re.sub(r"\W", "_", rel), ROOT / rel)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _MODULES[rel] = mod
+    return _MODULES[rel]
+
+
+def pads(mix: dict, cfg: dict) -> tuple[int, int]:
+    """(sequence length, scored rows) that hold any request of the mix,
+    the length a multiple of the reference's `Q_CHUNK`."""
+    q = reference(cfg).Q_CHUNK
     longest = mix["prompt"]["max"] + mix["output"]["max"]
-    return -(-longest // R.Q_CHUNK) * R.Q_CHUNK, mix["output"]["max"]
+    return -(-longest // q) * q, mix["output"]["max"]
 
 
 def choose(streams: list, seed: int, k: int) -> list:
@@ -72,6 +94,7 @@ def gaps(cfg: dict, seed: int, streams: list, S: int, Rn: int,
          control: tuple | None = None):
     """Per served token: (program gaps, control gaps or None), each an
     (n_streams, Rn) array with NaN past a stream's end."""
+    R = reference(cfg)
     toks, rows, served, valid = _arrays(streams, S, Rn)
     ref = R.logits_at(cfg, seed, toks, rows, group=GROUP)
     prog = np.concatenate([np.asarray(_gap(r, jnp.asarray(
@@ -94,7 +117,7 @@ def check(cfg: dict, seed: int, streams: list, budgets: list, mix: dict,
           vocab: int, limit: float) -> dict:
     """{check name: {"value", "limit"}}; a check passes when value <=
     limit."""
-    S, Rn = pads(mix)
+    S, Rn = pads(mix, cfg)
     wrong_len = sum(len(o) != b for (_, o), b in zip(streams, budgets))
     oov = sum(int(np.sum((np.asarray(o) < 0) | (np.asarray(o) >= vocab)))
               for _, o in streams)

@@ -20,6 +20,24 @@ output channel to bits_w and its activations per row to bits_a.
 
 Work is done layer by layer over groups of sequences, so the weights of
 one layer and the activations of a few sequences are all that is held.
+
+This is one reference module; a configuration file names its own under
+`"reference"` (a path from the checkout's root, this one by default),
+and `correct.py` loads it by path.  A reference module provides:
+
+  logits_at(cfg, seed, tokens, rows, quant=None, group=4)  required: as
+      below, the whole configuration file as `cfg`;
+  Q_CHUNK  required: the sequence length `tokens` is padded to a
+      multiple of;
+  KINDS    optional: {kind: fn(key, shape, dtype)} for weights that
+      `weights.draw`'s kinds do not cover (a zero bias, a state-space
+      constant); `system.make_params` draws such a kind with
+      `fn(weights.leaf_key(root, name, layer), shape, dtype)`, and the
+      module draws it the same way.
+
+Layers are absolute: stacked leaf row j under the program's
+`layers.pos{k}` is reference layer j * len(layer_pattern) + k, drawn
+with that index.
 """
 from __future__ import annotations
 

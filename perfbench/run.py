@@ -46,6 +46,12 @@ def load_config(entry: dict) -> dict:
     return json.loads((ROOT / entry["file"]).read_text())
 
 
+def kernels(cfg: dict) -> tuple:
+    """The kernels whose device time the trace reduction sums: the
+    configuration's `"kernels"`, by default the paged decode kernel."""
+    return tuple(cfg.get("kernels", ["paged_decode"]))
+
+
 def reader(name: str):
     spec = importlib.util.spec_from_file_location(
         f"perfbench_metric_{name}", HERE / "metrics" / f"{name}.py")
@@ -131,8 +137,7 @@ def run_cell(name: str, cfg: dict, mix: dict, metrics: list, seed: int,
     gc.collect()
     result = {}
     if trace:
-        red = tr.reduce(tr.export(str(trace_dir)),
-                        kernels=("paged_decode",))
+        red = tr.reduce(tr.export(str(trace_dir)), kernels=kernels(cfg))
         ctx["trace"] = red
         ctx["peaks"] = peaks.for_kind(devices[0].device_kind)
         vals = {m["name"]: reader(m["name"])(ctx) for m in metrics}

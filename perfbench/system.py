@@ -8,11 +8,21 @@ launcher's default.  The weights are not the launcher's `init_params`:
 the reference has to draw the same values without the program, so they
 come from `weights.draw` by leaf name, in one jitted call on the device,
 in the type they are served in.
+
+A configuration file brings its own architecture with three optional
+keys (PERF.md, section 4): `"program"`, `ModelConfig` fields set after
+the dense sizes (experts, LoRA ranks, `layer_pattern`, ...), never one
+that the dense keys set; `"leaves"`, `{program leaf suffix: [reference
+leaf name, kind]}` over `_LEAVES`, the longest matching suffix winning;
+and `"reference"`, the module whose `KINDS` may add weight kinds.  A
+stacked leaf under `layers.pos{k}` has one row per period, and its row j
+is drawn as reference layer j * len(layer_pattern) + k.
 """
 from __future__ import annotations
 
 import jax
 
+from perfbench import correct
 from perfbench import weights as W
 
 # program tree leaf name -> (reference leaf name, weight kind)
@@ -40,13 +50,22 @@ def model_config(cfg: dict, args):
     """The launcher's ModelConfig with the sizes the file states: the file
     holds the configuration as it is run."""
     from repro.launch import serve as launch
-    mc = launch.model_config(args).replace(
+    fields = dict(
         num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
         num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        num_kv_heads=cfg["num_key_value_heads"],
+        head_dim=cfg.get("head_dim"),
         d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
         rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
         dtype=cfg["dtype"])
+    program = cfg.get("program", {})
+    stated = sorted(set(program) & set(fields))
+    if stated:
+        raise ValueError(f"\"program\" restates {stated}, which the "
+                         f"file's dense keys set")
+    fields.update({k: tuple(v) if isinstance(v, list) else v
+                   for k, v in program.items()})
+    mc = launch.model_config(args).replace(**fields)
     if mc.page_size != cfg["engine"]["page_size"]:
         raise ValueError(f"program page size {mc.page_size} differs from "
                          f"the file's {cfg['engine']['page_size']}")
@@ -57,6 +76,39 @@ def _path_name(path) -> str:
     return ".".join(str(getattr(p, "key", p)) for p in path)
 
 
+def leaf_table(cfg: dict) -> dict:
+    """`_LEAVES` updated by the file's `"leaves"`."""
+    return {**_LEAVES, **{k: tuple(v)
+                          for k, v in cfg.get("leaves", {}).items()}}
+
+
+def match(table: dict, pstr: str) -> tuple:
+    """(reference name, kind) of the longest suffix of `pstr`, on a `.`
+    boundary, that `table` holds."""
+    keys = [k for k in table if pstr == k or pstr.endswith("." + k)]
+    if not keys:
+        raise ValueError(f"no seeded draw for program leaf {pstr}")
+    return table[max(keys, key=len)]
+
+
+def position(pstr: str) -> int:
+    """k of a stacked leaf under `layers.pos{k}`: its row j is reference
+    layer j * len(layer_pattern) + k."""
+    return int(pstr.split(".")[1].removeprefix("pos"))
+
+
+def drawer(cfg: dict):
+    """`weights.draw`, with the reference module's `KINDS` looked up
+    first: `KINDS[kind](leaf key, shape, dtype)`."""
+    kinds = getattr(correct.reference(cfg), "KINDS", {})
+
+    def draw(root, name, layer, shape, kind, dtype):
+        if kind in kinds:
+            return kinds[kind](W.leaf_key(root, name, layer), shape, dtype)
+        return W.draw(root, name, layer, shape, kind, dtype)
+    return draw
+
+
 def make_params(cfg: dict, mc, seed: int):
     """The program's parameter tree, every leaf drawn from the seed; with
     `"weights": "int8"` every linear is a served int8 `QuantizedTensor`."""
@@ -65,21 +117,20 @@ def make_params(cfg: dict, mc, seed: int):
 
     shapes = jax.eval_shape(lambda: M.init_params(mc, jax.random.PRNGKey(0)))
     int8 = cfg["weights"] == "int8"
+    table, draw = leaf_table(cfg), drawer(cfg)
 
     def build(root):
         def leaf(path, sd):
             pstr = _path_name(path)
-            key = next((k for k in _LEAVES if pstr.endswith(k)), None)
-            if key is None:
-                raise ValueError(f"no seeded draw for program leaf {pstr}")
-            name, kind = _LEAVES[key]
+            name, kind = match(table, pstr)
             if int8 and kind == "matrix":
                 kind = "int8"
             if pstr.startswith("layers."):
-                out = W.stacked(root, name, mc.num_layers, sd.shape[1:],
-                                kind, sd.dtype)
+                out = W.stacked(root, name, sd.shape[0], sd.shape[1:], kind,
+                                sd.dtype, draw, stride=len(mc.layer_pattern),
+                                offset=position(pstr))
             else:
-                out = W.draw(root, name, 0, sd.shape, kind, sd.dtype)
+                out = draw(root, name, 0, sd.shape, kind, sd.dtype)
             if kind == "int8":
                 return QuantizedTensor(*out, 8, False, tuple(sd.shape))
             return out

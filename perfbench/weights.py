@@ -93,10 +93,18 @@ def draw(root, name: str, layer: int, shape, kind: str,
     raise ValueError(f"unknown weight kind {kind!r}")
 
 
-def stacked(root, name: str, n_layers: int, shape, kind: str,
-            dtype=jnp.bfloat16):
-    """`draw` for layers 0..n_layers-1, stacked on a leading axis; inside
-    a jit this holds one layer's temporaries at a time."""
+def stacked(root, name: str, rows: int, shape, kind: str,
+            dtype=jnp.bfloat16, draw_fn=draw, stride: int = 1,
+            offset: int = 0):
+    """`draw_fn` (by default `draw`) for layers offset, offset + stride,
+    ... (`rows` of them), stacked on a leading axis; inside a jit this
+    holds one layer's temporaries at a time."""
+    layers = jnp.arange(rows, dtype=jnp.uint32)
+    # stride 1 and offset 0 leave a bare iota: a one-entry layer pattern
+    # draws with the same program as a plain range of layers
+    if stride != 1:
+        layers = layers * stride
+    if offset:
+        layers = layers + offset
     return jax.lax.map(
-        lambda i: draw(root, name, i, shape, kind, dtype),
-        jnp.arange(n_layers, dtype=jnp.uint32))
+        lambda i: draw_fn(root, name, i, shape, kind, dtype), layers)
