@@ -1,14 +1,14 @@
-"""Aggregated registry of the 10 assigned architectures."""
+"""Aggregated registry of the architectures."""
 from __future__ import annotations
 
-from repro.configs import (dbrx_132b, granite_8b, internlm2_20b,
-                           jamba_1_5_large_398b, llama_3_2_vision_11b,
-                           minicpm3_4b, musicgen_large, qwen3_moe_30b_a3b,
-                           starcoder2_7b, xlstm_1_3b)
+from repro.configs import (dbrx_132b, deepseek_v2_lite, granite_8b,
+                           internlm2_20b, jamba_1_5_large_398b,
+                           llama_3_2_vision_11b, minicpm3_4b, musicgen_large,
+                           qwen3_moe_30b_a3b, starcoder2_7b, xlstm_1_3b)
 
 _MODULES = (dbrx_132b, qwen3_moe_30b_a3b, jamba_1_5_large_398b, minicpm3_4b,
             internlm2_20b, starcoder2_7b, granite_8b, llama_3_2_vision_11b,
-            musicgen_large, xlstm_1_3b)
+            musicgen_large, xlstm_1_3b, deepseek_v2_lite)
 
 FULL = {m.ARCH_ID: m.full_config for m in _MODULES}
 SMOKE = {m.ARCH_ID: m.smoke_config for m in _MODULES}
@@ -31,6 +31,6 @@ def shape_applicable(arch: str, shape: str) -> bool:
 
 
 def all_cells() -> list[tuple[str, str]]:
-    """The 40 assigned (arch × shape) cells; inapplicable ones are reported
-    as skipped-by-design (8 long_500k cells for full-attention archs)."""
+    """Every (arch × shape) cell; inapplicable ones are reported as
+    skipped-by-design (long_500k cells for full-attention archs)."""
     return [(a, s) for a in FULL for s in SHAPES]

@@ -7,8 +7,11 @@ repeating period as `"<mixer>+<ff>"` entries:
           | mlstm | slstm
   ff:     dense (SwiGLU) | moe | none
 
-The stack scans over `num_layers / len(layer_pattern)` periods with stacked
-parameters, which keeps the lowered HLO compact for 40–72-layer models.
+The stack scans over `(num_layers - first_dense_layers) / len(layer_pattern)`
+periods with stacked parameters, which keeps the lowered HLO compact for
+40–72-layer models.  `first_dense_layers` leading blocks (the pattern's
+first mixer with a dense FFN, DeepSeek's `first_k_dense_replace`) run
+before the scanned periods.
 """
 from __future__ import annotations
 
@@ -30,11 +33,22 @@ class ModelConfig:
     vocab_size: int
     layer_pattern: tuple[str, ...] = ("attn+dense",)
     head_dim: int | None = None      # default d_model // num_heads
+    first_dense_layers: int = 0      # leading "<mixer>+dense" blocks
 
     # --- MoE ---
     num_experts: int = 0
     experts_per_token: int = 0
     moe_d_ff: int = 0                # per-expert hidden dim (0 → d_ff)
+    router_experts: int = 0          # experts the router scores (0 →
+    #                                  num_experts); with more than
+    #                                  num_experts this layer holds the
+    #                                  share [first_expert, first_expert +
+    #                                  num_experts) of an expert-parallel
+    #                                  deployment and computes that part
+    first_expert: int = 0
+    n_shared_experts: int = 0        # always-on SwiGLU of width
+    #                                  n_shared_experts * expert_d_ff
+    norm_topk_prob: bool = True      # renormalise the top-k probabilities
     moe_dispatch: str = "sort"       # "sort" (default) | "cumsum"
     #   sort: argsort-based rank-in-expert, O(T·k log T·k), no E-wide
     #   temporaries — adopted as default after the §Perf hillclimb;
@@ -50,6 +64,16 @@ class ModelConfig:
 
     # --- attention ---
     rope_theta: float = 10_000.0
+    # YaRN scaling of the MLA rope dims (DeepSeek-V2 `rope_scaling`); a
+    # factor of 1 is plain RoPE
+    rope_scaling_factor: float = 1.0
+    rope_original_max_position: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    rope_interleaved: bool = False   # MLA rope rotates pairs (2i, 2i+1),
+    #                                  not the two halves
     q_lora_rank: int = 0             # MLA
     kv_lora_rank: int = 0            # MLA
     qk_nope_dim: int = 64            # MLA per-head dims
@@ -89,7 +113,8 @@ class ModelConfig:
     logical_rules: str = "default"   # sharding rule set name
 
     def __post_init__(self):
-        if self.num_layers % len(self.layer_pattern):
+        if (self.num_layers - self.first_dense_layers) \
+                % len(self.layer_pattern):
             raise ValueError(
                 f"{self.name}: num_layers {self.num_layers} not divisible by "
                 f"pattern period {len(self.layer_pattern)}")
@@ -105,7 +130,17 @@ class ModelConfig:
 
     @property
     def n_periods(self) -> int:
-        return self.num_layers // len(self.layer_pattern)
+        return (self.num_layers - self.first_dense_layers) \
+            // len(self.layer_pattern)
+
+    @property
+    def dense_spec(self) -> str:
+        """Block spec of the leading dense layers."""
+        return self.layer_pattern[0].split("+")[0] + "+dense"
+
+    @property
+    def n_router_experts(self) -> int:
+        return self.router_experts or self.num_experts
 
     @property
     def compute_dtype(self):
@@ -121,14 +156,16 @@ class ModelConfig:
     # ---- parameter count (for roofline MODEL_FLOPS and memory budgets) ----
     def param_count(self) -> int:
         return sum(_layer_params(self, spec) for spec in self.layer_pattern) \
-            * self.n_periods + 2 * self.vocab_size * self.d_model
+            * self.n_periods + 2 * self.vocab_size * self.d_model \
+            + self.first_dense_layers * _layer_params(self, self.dense_spec)
 
     def active_param_count(self) -> int:
         """Params touched per token (MoE: top-k experts only) — the N in
         MODEL_FLOPS = 6·N_active·D."""
         if not self.num_experts:
             return self.param_count()
-        total = 2 * self.vocab_size * self.d_model
+        total = 2 * self.vocab_size * self.d_model \
+            + self.first_dense_layers * _layer_params(self, self.dense_spec)
         for spec in self.layer_pattern:
             n = _layer_params(self, spec)
             if spec.endswith("+moe"):
@@ -149,8 +186,9 @@ def _layer_params(cfg: ModelConfig, spec: str) -> int:
         n += d * cfg.num_heads * cfg.hd + d * cfg.hd * cfg.num_kv_heads * 2 \
             + cfg.num_heads * cfg.hd * d
     elif mixer == "mla":
-        qr = cfg.q_lora_rank or d
-        n += d * qr + qr * cfg.num_heads * (cfg.qk_nope_dim + cfg.qk_rope_dim)
+        q = cfg.num_heads * (cfg.qk_nope_dim + cfg.qk_rope_dim)
+        n += d * cfg.q_lora_rank + cfg.q_lora_rank * q if cfg.q_lora_rank \
+            else d * q
         n += d * (cfg.kv_lora_rank + cfg.qk_rope_dim)
         n += cfg.kv_lora_rank * cfg.num_heads * (cfg.qk_nope_dim + cfg.v_head_dim)
         n += cfg.num_heads * cfg.v_head_dim * d
@@ -170,5 +208,6 @@ def _layer_params(cfg: ModelConfig, spec: str) -> int:
     if ff == "dense":
         n += 3 * d * cfg.d_ff
     elif ff == "moe":
-        n += cfg.num_experts * 3 * d * cfg.expert_d_ff + d * cfg.num_experts
+        n += (cfg.num_experts + cfg.n_shared_experts) * 3 * d \
+            * cfg.expert_d_ff + d * cfg.n_router_experts
     return n

@@ -34,7 +34,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import paged_attention as pk
-from repro.models.layers import apply_rope, dense, init_dense, init_rmsnorm, rmsnorm
+from repro.models.layers import (apply_rope, apply_rope_pairs, dense,
+                                 init_dense, init_rmsnorm, rmsnorm,
+                                 yarn_freqs, yarn_mscale)
 
 Q_CHUNK = 1024
 
@@ -170,14 +172,15 @@ def paged_view(pool, pv: PagedKV):
 # core softmax attention
 # ---------------------------------------------------------------------------
 
-def _attend(q, k, v, mask):
-    """q: (B,Sq,H,hd) k/v: (B,Sk,Hkv,hd); mask: (Sq,Sk) or (B,1,Sq,Sk)."""
+def _attend(q, k, v, mask, scale=None):
+    """q: (B,Sq,H,hd) k/v: (B,Sk,Hkv,hd); mask: (Sq,Sk) or (B,1,Sq,Sk);
+    scores are scaled by `scale`, by default 1/sqrt(hd)."""
     B, Sq, H, hd = q.shape
     Hkv = k.shape[2]
     group = H // Hkv
     qg = q.reshape(B, Sq, Hkv, group, hd)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32)
-    scores = scores / math.sqrt(hd)
+    scores = scores / math.sqrt(hd) if scale is None else scores * scale
     if mask.ndim == 2:
         mask = mask[None, None, None]
     else:
@@ -188,14 +191,14 @@ def _attend(q, k, v, mask):
     return out.reshape(B, Sq, H, v.shape[-1])   # v head dim may differ (MLA)
 
 
-def causal_attention(q, k, v, q_offset=0):
+def causal_attention(q, k, v, q_offset=0, scale=None):
     """Query-chunked causal attention (training / prefill)."""
     B, Sq, H, hd = q.shape
     Sk = k.shape[1]
     if Sq <= Q_CHUNK:
         mask = (jnp.arange(Sk)[None, :] <=
                 (jnp.arange(Sq)[:, None] + q_offset))
-        return _attend(q, k, v, mask)
+        return _attend(q, k, v, mask, scale)
     # ragged sequences run the full chunks through the scanned body and the
     # leftover rows (< Q_CHUNK of them) through one extra trailing _attend
     n_chunks = Sq // Q_CHUNK
@@ -206,7 +209,7 @@ def causal_attention(q, k, v, q_offset=0):
         offs = q_offset + i * Q_CHUNK
         mask = (jnp.arange(Sk)[None, :] <=
                 (jnp.arange(Q_CHUNK)[:, None] + offs))
-        return _attend(qi, k, v, mask)
+        return _attend(qi, k, v, mask, scale)
 
     out = jax.lax.map(lambda args: body(*args),
                       (jnp.arange(n_chunks), qc))
@@ -215,8 +218,8 @@ def causal_attention(q, k, v, q_offset=0):
         tail = Sq - Sq_full
         mask = (jnp.arange(Sk)[None, :] <=
                 (jnp.arange(tail)[:, None] + q_offset + Sq_full))
-        out = jnp.concatenate([out, _attend(q[:, Sq_full:], k, v, mask)],
-                              axis=1)
+        out = jnp.concatenate(
+            [out, _attend(q[:, Sq_full:], k, v, mask, scale)], axis=1)
     return out
 
 
@@ -226,7 +229,7 @@ def decode_attention(q, k_cache, v_cache, pos):
     return chunk_attention(q, k_cache, v_cache, pos[:, None])
 
 
-def chunk_attention(q, k_cache, v_cache, positions):
+def chunk_attention(q, k_cache, v_cache, positions, scale=None):
     """Causal attention of a prefill chunk at an arbitrary offset.
 
     q: (B,Sq,H,hd) chunk queries; caches: (B,S_max,Hkv,hd) already updated
@@ -236,7 +239,7 @@ def chunk_attention(q, k_cache, v_cache, positions):
     are masked to exact zeros), and at offset>0 it sees all earlier chunks."""
     Sk = k_cache.shape[1]
     mask = jnp.arange(Sk)[None, None, :] <= positions[:, :, None]  # (B,Sq,Sk)
-    return _attend(q, k_cache, v_cache, mask[:, None])
+    return _attend(q, k_cache, v_cache, mask[:, None], scale)
 
 
 # ---------------------------------------------------------------------------
@@ -437,16 +440,19 @@ def decode_attention_q(q, cache, positions):
 # ---------------------------------------------------------------------------
 
 def init_mla(key, cfg):
+    """With `q_lora_rank` 0 the query is one projection `w_q` (DeepSeek-V2-
+    Lite); otherwise a LoRA `w_dq`, `q_norm`, `w_uq`."""
     d = cfg.d_model
     H = cfg.num_heads
     qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
     nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     ks = jax.random.split(key, 8)
     dt = cfg.compute_dtype
-    return {
-        "w_dq": init_dense(ks[0], d, qr, dt),
-        "q_norm": init_rmsnorm(qr, dt),
-        "w_uq": init_dense(ks[1], qr, H * (nope + rope), dt),
+    q = {"w_dq": init_dense(ks[0], d, qr, dt),
+         "q_norm": init_rmsnorm(qr, dt),
+         "w_uq": init_dense(ks[1], qr, H * (nope + rope), dt)} if qr \
+        else {"w_q": init_dense(ks[0], d, H * (nope + rope), dt)}
+    return q | {
         "w_dkv": init_dense(ks[2], d, kvr, dt),
         "kv_norm": init_rmsnorm(kvr, dt),
         "w_kr": init_dense(ks[3], d, rope, dt),
@@ -456,26 +462,50 @@ def init_mla(key, cfg):
     }
 
 
+def _mla_rope(cfg):
+    """(rope(x, positions), softmax scale or None for 1/sqrt(hd)) of MLA's
+    rope dims: `apply_rope` over the two halves, or, with
+    `rope_interleaved`, DeepSeek-V2's adjacent pairs at YaRN frequencies
+    (`yarn_freqs`; cos/sin scaled by mscale / mscale_all_dim, scores by
+    mscale_all_dim^2 / sqrt(nope + rope))."""
+    if not cfg.rope_interleaved:
+        return (lambda t, pos: apply_rope(t, pos, cfg.rope_theta)), None
+    f = cfg.rope_scaling_factor
+    freqs = yarn_freqs(cfg.qk_rope_dim, cfg.rope_theta, f,
+                       cfg.rope_original_max_position, cfg.rope_beta_fast,
+                       cfg.rope_beta_slow)
+    m_all = yarn_mscale(f, cfg.rope_mscale_all_dim) \
+        if cfg.rope_mscale_all_dim else 1.0
+    cs = yarn_mscale(f, cfg.rope_mscale) / m_all
+    scale = m_all * m_all / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    return (lambda t, pos: apply_rope_pairs(t, pos, freqs, cs)), scale
+
+
 def mla(p, x, cfg, positions, cache=None, cache_pos=None, paged=None,
         layer=None):
     """Latent attention; the cache stores only (c_kv, k_rope) — the paper's
     BRAMAC quantization applies to every projection here as well.  The
     stacked cache is written and read at `layer`; the write is scoped
-    `kv_write`, the rest `attention`, as in gqa."""
+    `kv_write`, the rest `attention`, as in gqa.  The cached rows are
+    up-projected by `w_uk`/`w_uv` at every read (the un-absorbed form)."""
     B, S, _ = x.shape
     H = cfg.num_heads
     nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    rotate, scale = _mla_rope(cfg)
     with jax.named_scope("attention"):
-        q = dense(rmsnorm(p["q_norm"], dense(x, p["w_dq"], cfg.quant),
-                          cfg.norm_eps), p["w_uq"], cfg.quant)
+        if cfg.q_lora_rank:
+            q = dense(rmsnorm(p["q_norm"], dense(x, p["w_dq"], cfg.quant),
+                              cfg.norm_eps), p["w_uq"], cfg.quant)
+        else:
+            q = dense(x, p["w_q"], cfg.quant)
         q = q.reshape(B, S, H, nope + rope)
         q_nope, q_rope = q[..., :nope], q[..., nope:]
-        q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+        q_rope = rotate(q_rope, positions)
 
         c_kv = rmsnorm(p["kv_norm"], dense(x, p["w_dkv"], cfg.quant),
                        cfg.norm_eps)
-        k_rope = apply_rope(dense(x, p["w_kr"], cfg.quant)[:, :, None, :],
-                            positions, cfg.rope_theta)[:, :, 0]  # (B,S,rope)
+        k_rope = rotate(dense(x, p["w_kr"], cfg.quant)[:, :, None, :],
+                        positions)[:, :, 0]                # (B,S,rope)
 
     new_cache = None
     if cache is not None:
@@ -499,9 +529,9 @@ def mla(p, x, cfg, positions, cache=None, cache_pos=None, paged=None,
         q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
 
         if cache is not None:                    # decode or prefill chunk
-            out = chunk_attention(q_full, k, v, positions)
+            out = chunk_attention(q_full, k, v, positions, scale)
         else:
-            out = causal_attention(q_full, k[:, :S], v[:, :S])
+            out = causal_attention(q_full, k[:, :S], v[:, :S], scale=scale)
         out = dense(out.reshape(B, S, H * vd), p["wo"], cfg.quant)
     return out, new_cache
 

@@ -25,10 +25,14 @@ from repro.parallel.sharding import constrain
 
 def init_params(cfg: ModelConfig, key) -> dict:
     k1, k2 = jax.random.split(key)
-    return {"embed": init_embed(k1, cfg.vocab_size, cfg.d_model,
-                                cfg.compute_dtype),
-            "final_norm": init_rmsnorm(cfg.d_model, cfg.compute_dtype),
-            "layers": tf.init_stack(k2, cfg)}
+    params = {"embed": init_embed(k1, cfg.vocab_size, cfg.d_model,
+                                  cfg.compute_dtype),
+              "final_norm": init_rmsnorm(cfg.d_model, cfg.compute_dtype),
+              "layers": tf.init_stack(k2, cfg)}
+    if cfg.first_dense_layers:
+        params["dense_layers"] = tf.init_leading(jax.random.fold_in(key, 2),
+                                                 cfg)
+    return params
 
 
 def _inputs_to_hidden(params, batch, cfg):
@@ -71,9 +75,15 @@ def forward(params, batch, cfg: ModelConfig, caches=None, cache_pos=None,
     ve = batch.get("vision_embeds")
     if ve is not None:
         ve = ve.astype(cfg.compute_dtype)
+    if cfg.first_dense_layers:
+        x, aux0, caches = tf.leading_apply(
+            params["dense_layers"], x, cfg, positions=positions,
+            vision_embeds=ve, caches=caches, cache_pos=cache_pos, paged=paged)
     x, aux, new_caches = tf.stack_apply(
         params["layers"], x, cfg, positions=positions, vision_embeds=ve,
         caches=caches, cache_pos=cache_pos, paged=paged)
+    if cfg.first_dense_layers:
+        aux = aux + aux0
     if last_only:
         x = x[:, -1:]
     elif gather_pos is not None:

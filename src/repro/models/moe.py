@@ -1,15 +1,34 @@
 """Mixture-of-Experts FFN: top-k routing, capacity-based dispatch, EP.
 
-Tokens are scattered into a per-expert capacity buffer (E, C, d) — the
-Switch-Transformer dispatch — so expert compute is E·C·(3·d·ff) ≈ the
-*active* FLOPs (k/E of dense-all-experts), which keeps the roofline's
-MODEL_FLOPS/HLO_FLOPs ratio honest.  Experts are sharded over the `model`
-mesh axis (expert parallelism); the scatter/gather lowers to all-to-all-
-style collectives under pjit.
+Serving (a forward with a cache) is dropless: an expert receives at most
+one assignment per token, so a buffer of T rows per expert, token t in
+row t, holds every assignment, and a token's output never depends on
+its batch companions.  Every expert runs over all T rows, and the
+combine weight is zero where a token did not choose it.  Only an
+explicit `per_source` dispatch on a mesh still hands serving to
+`ep.ep_moe`'s lossy path.
+
+Training keeps capacity drops: tokens are scattered into a per-expert
+capacity buffer (E, C, d) — the Switch-Transformer dispatch — so expert
+compute is E·C·(3·d·ff) ≈ the *active* FLOPs (k/E of dense-all-experts),
+which keeps the roofline's MODEL_FLOPS/HLO_FLOPs ratio honest.  Tokens
+overflowing an expert's capacity are dropped (standard; tests use a
+no-drop capacity to check exactness against the dense reference).
+Experts are sharded over the `model` mesh axis (expert parallelism); the
+scatter/gather lowers to all-to-all-style collectives under pjit.
+
+A serving layer may hold a share of the experts (`cfg.router_experts` >
+`cfg.num_experts`): the router scores all `router_experts`, and the layer
+computes the part of the result its experts [`first_expert`,
+`first_expert + num_experts`) give; assignments to the others add
+nothing here (on one chip of an expert-parallel deployment, without the
+exchange).  `n_shared_experts` adds an always-on SwiGLU of width
+`n_shared_experts * expert_d_ff`; `norm_topk_prob=False` weights the
+chosen experts by their raw probabilities.
 
 Router math is f32; a Switch-style load-balancing aux loss is returned.
-Tokens overflowing an expert's capacity are dropped (standard; tests use a
-no-drop capacity to check exactness against the dense reference).
+Named scopes inside `mlp`: `route` (router, top-k, combine), `experts`
+(the routed expert matmuls), `shared` (the shared experts).
 """
 from __future__ import annotations
 
@@ -18,7 +37,7 @@ import jax.numpy as jnp
 
 from repro.core import bramac_linear as bl
 from repro.core.quant import QuantizedTensor
-from repro.models.layers import he_init
+from repro.models.layers import he_init, init_mlp, swiglu
 from repro.parallel import ep, sharding
 
 
@@ -42,12 +61,16 @@ def init_moe(key, cfg):
     d, ff, E = cfg.d_model, cfg.expert_d_ff, cfg.num_experts
     ks = jax.random.split(key, 4)
     dt = cfg.compute_dtype
-    return {
-        "router": he_init(ks[0], (d, E), jnp.float32),
+    p = {
+        "router": he_init(ks[0], (d, cfg.n_router_experts), jnp.float32),
         "w_gate": he_init(ks[1], (E, d, ff), dt, fan_in=d),
         "w_up": he_init(ks[2], (E, d, ff), dt, fan_in=d),
         "w_down": he_init(ks[3], (E, ff, d), dt, fan_in=ff),
     }
+    if cfg.n_shared_experts:
+        p["shared"] = init_mlp(jax.random.fold_in(key, 4), d,
+                               cfg.n_shared_experts * ff, dt)
+    return p
 
 
 def moe_capacity(T: int, E: int, k: int, capacity_factor: float) -> int:
@@ -60,8 +83,11 @@ def moe_capacity(T: int, E: int, k: int, capacity_factor: float) -> int:
 
 @jax.named_scope("mlp")
 def moe(p, x, cfg, capacity_factor: float | None = None,
-        dispatch: str | None = None):
+        dispatch: str | None = None, dropless: bool = False):
     """x: (B, S, d) → (out, aux_loss).
+
+    `dropless` (serving) takes the dropless dispatch of the module
+    docstring; otherwise tokens past an expert's capacity are dropped.
 
     `capacity_factor` / `dispatch` default to `cfg.moe_capacity_factor` /
     `cfg.ep_dispatch` (the knobs Engine and launch/serve.py plumb down).
@@ -92,11 +118,56 @@ def moe(p, x, cfg, capacity_factor: float | None = None,
     T = B * S
     xf = x.reshape(T, d)
 
-    logits = xf.astype(jnp.float32) @ p["router"]           # (T,E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)                  # (T,k)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    with jax.named_scope("route"):
+        logits = xf.astype(jnp.float32) @ p["router"]       # (T,E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_i = jax.lax.top_k(probs, k)              # (T,k)
+        if cfg.norm_topk_prob:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if dropless:
+        out = _dropless(p, xf, top_p, top_i, cfg).reshape(B, S, d)
+    else:
+        if cfg.n_router_experts != E:
+            raise ValueError("an expert layer holding a share of the "
+                             "experts serves dropless only")
+        out = _capacity(p, xf, top_p, top_i, E, k, capacity_factor,
+                        cfg).reshape(B, S, d)
+    if cfg.n_shared_experts:
+        with jax.named_scope("shared"):
+            out = out + swiglu(p["shared"], x, cfg.quant)
 
+    # ---- Switch load-balance loss ----
+    frac_tokens = jnp.mean(
+        jax.nn.one_hot(top_i, cfg.n_router_experts, dtype=jnp.float32),
+        axis=(0, 1))
+    frac_probs = jnp.mean(probs, axis=0)
+    aux = cfg.n_router_experts * jnp.sum(frac_tokens * frac_probs)
+    return out, aux
+
+
+def _dropless(p, xf, top_p, top_i, cfg):
+    """(T, d) routed output of the held experts: each runs over all T
+    rows (capacity T, token t in row t), combined by the chosen experts'
+    weights (zero for a token that did not choose an expert, and for
+    experts held elsewhere)."""
+    E, T = cfg.num_experts, xf.shape[0]
+    with jax.named_scope("route"):
+        held = jax.nn.one_hot(top_i - cfg.first_expert, E,
+                              dtype=jnp.float32)             # (T,k,E)
+        combine = jnp.einsum("tk,tke->et", top_p, held).astype(xf.dtype)
+    with jax.named_scope("experts"):
+        buf = jnp.broadcast_to(xf[None], (E, T, xf.shape[1]))
+        g = _expert_matmul(buf, p["w_gate"])
+        u = _expert_matmul(buf, p["w_up"])
+        ye = _expert_matmul(jax.nn.silu(g) * u, p["w_down"])
+    with jax.named_scope("route"):
+        return jnp.einsum("etd,et->td", ye, combine)
+
+
+def _capacity(p, xf, top_p, top_i, E, k, capacity_factor, cfg):
+    """(T, d) output of the capacity dispatch (training): assignments
+    past an expert's capacity C are dropped."""
+    T, d = xf.shape
     # ---- capacity dispatch ----
     C = moe_capacity(T, E, k, capacity_factor)
     a = top_i.reshape(T * k)                                # assignments
@@ -108,7 +179,7 @@ def moe(p, x, cfg, capacity_factor: float | None = None,
     pos_c = jnp.where(keep, pos, C - 1)
 
     xk = jnp.repeat(xf, k, axis=0)                          # (T*k, d)
-    buf = jnp.zeros((E, C, d), x.dtype)
+    buf = jnp.zeros((E, C, d), xf.dtype)
     buf = buf.at[a, pos_c].add(jnp.where(keep[:, None], xk, 0))
 
     # ---- expert compute (EP: E sharded over `model`) ----
@@ -118,16 +189,9 @@ def moe(p, x, cfg, capacity_factor: float | None = None,
 
     # ---- combine ----
     yk = ye[a, pos_c]                                       # (T*k, d)
-    w = (top_p.reshape(T * k).astype(x.dtype)
-         * keep.astype(x.dtype))[:, None]
-    out = jnp.sum((yk * w).reshape(T, k, d), axis=1).reshape(B, S, d)
-
-    # ---- Switch load-balance loss ----
-    frac_tokens = jnp.mean(
-        jax.nn.one_hot(top_i, E, dtype=jnp.float32), axis=(0, 1))
-    frac_probs = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(frac_tokens * frac_probs)
-    return out, aux
+    w = (top_p.reshape(T * k).astype(xf.dtype)
+         * keep.astype(xf.dtype))[:, None]
+    return jnp.sum((yk * w).reshape(T, k, d), axis=1)
 
 
 def _rank_in_expert_cumsum(a: jax.Array, E: int) -> jax.Array:

@@ -5,6 +5,13 @@ the repeating pattern are stacked over the `n_periods` scan axis, so a
 72-layer model lowers as one scanned period — compact HLO, fast dry-run
 compiles, and the FSDP all-gather of each period's params happens inside
 the scan (overlappable by the XLA latency-hiding scheduler).
+
+`cfg.first_dense_layers` leading blocks (`cfg.dense_spec`: the pattern's
+first mixer with a dense FFN) run before the scan, unrolled: their
+params sit under `dense_layers.pos{i}` (one unstacked block each) and
+their cache under the cache tree's `dense_layers` key, stacked over
+them like a scanned position's, so pool code treats it as one more
+stacked pool.
 """
 from __future__ import annotations
 
@@ -131,8 +138,10 @@ def block_apply(p, x, cfg, spec, *, positions, vision_embeds=None,
     if ff == "dense":
         x = x + mlp(p["mlp"], rmsnorm(p["norm2"], x, cfg.norm_eps), cfg.quant)
     elif ff == "moe":
+        # serving (a cache is passed) routes dropless: a token's output
+        # must not depend on its batch companions
         y, aux = moe_mod.moe(p["moe"], rmsnorm(p["norm2"], x, cfg.norm_eps),
-                             cfg)
+                             cfg, dropless=cache is not None)
         x = x + y
     return x, aux, new_cache
 
@@ -151,13 +160,29 @@ def init_stack(key, cfg):
     return params
 
 
+def init_leading(key, cfg):
+    """{"pos{i}": block params} of the leading dense blocks."""
+    return {f"pos{i}": init_block(jax.random.fold_in(key, i), cfg,
+                                  cfg.dense_spec)
+            for i in range(cfg.first_dense_layers)}
+
+
+def _stacked_specs(cfg):
+    """(cache key, block spec, rows) of every stacked cache entry."""
+    out = [(f"pos{i}", spec, cfg.n_periods)
+           for i, spec in enumerate(cfg.layer_pattern)]
+    if cfg.first_dense_layers:
+        out.append(("dense_layers", cfg.dense_spec, cfg.first_dense_layers))
+    return out
+
+
 def init_stack_cache(cfg, batch, max_seq, dtype, num_pages=None):
     caches = {}
-    for i, spec in enumerate(cfg.layer_pattern):
+    for key, spec, rows in _stacked_specs(cfg):
         one = init_block_cache(cfg, spec, batch, max_seq, dtype, num_pages)
-        caches[f"pos{i}"] = jax.tree_util.tree_map(
-            lambda a: jnp.broadcast_to(a, (cfg.n_periods,) + a.shape).copy(),
-            one)
+        caches[key] = jax.tree_util.tree_map(
+            lambda a, rows=rows: jnp.broadcast_to(
+                a, (rows,) + a.shape).copy(), one)
     return caches
 
 
@@ -170,14 +195,40 @@ def stack_cache_pool_flags(cfg):
     stacked leaf is (n_periods, num_pages, page_size, ...) — the page
     axis is axis 1) while leaving per-slot leaves untouched."""
     flags = {}
-    for i, spec in enumerate(cfg.layer_pattern):
+    for key, spec, _ in _stacked_specs(cfg):
         mixer, _ = parse_spec(spec)
         is_pool = mixer in ("attn", "mla")
         shapes = jax.eval_shape(
             lambda s=spec: init_block_cache(cfg, s, 1, cfg.page_size,
                                             cfg.compute_dtype, num_pages=1))
-        flags[f"pos{i}"] = jax.tree_util.tree_map(lambda _: is_pool, shapes)
+        flags[key] = jax.tree_util.tree_map(lambda _: is_pool, shapes)
     return flags
+
+
+def leading_apply(params, x, cfg, *, positions, vision_embeds=None,
+                  caches=None, cache_pos=None, paged=None):
+    """The leading dense blocks, unrolled, each reading and writing row i
+    of the `dense_layers` cache.  Returns (x, aux_total, caches)."""
+    aux_total = jnp.zeros((), jnp.float32)
+    for i in range(cfg.first_dense_layers):
+        bundle = None if paged is None else dataclasses.replace(paged,
+                                                                layer=i)
+
+        def block(p, x, cache, bundle=bundle, i=i):
+            return block_apply(p, x, cfg, cfg.dense_spec,
+                               positions=positions,
+                               vision_embeds=vision_embeds, cache=cache,
+                               cache_pos=cache_pos, paged=bundle, layer=i)
+        if cfg.remat:
+            block = jax.checkpoint(
+                block, policy=jax.checkpoint_policies.nothing_saveable)
+        x, aux, nc = block(params[f"pos{i}"], x,
+                           None if caches is None else caches["dense_layers"])
+        aux_total += aux
+        if caches is not None:
+            caches = {**caches, "dense_layers": nc}
+        x = constrain(x, "batch", "act_seq", None)
+    return x, aux_total, caches
 
 
 def stack_apply(params, x, cfg, *, positions, vision_embeds=None,

@@ -259,9 +259,10 @@ class Engine:
                     f"has no page representation to transfer "
                     f"(layer_pattern={cfg.layer_pattern})")
         # --- speculation (silent opt-outs: recurrent state cannot rewind
-        # a rejected draft; xattn decode needs vision inputs; MoE capacity
-        # drops depend on tokens-per-call, breaking verify/decode parity;
-        # disagg drafter state has no page representation to transfer)
+        # a rejected draft; xattn decode needs vision inputs; MoE stays
+        # off although serving is dropless (no cell runs speculation on
+        # experts yet: ROADMAP Queue 3, item 4); disagg drafter state has
+        # no page representation to transfer)
         spec_ok = not recurrent and not self.disagg \
             and not any("xattn" in s or "moe" in s
                         for s in cfg.layer_pattern)

@@ -1,4 +1,4 @@
-"""Pin the 10 assigned architecture configs to the assignment sheet."""
+"""Pin the architecture configs to their published sizes."""
 import pytest
 
 from repro.configs import ARCH_IDS, get_config
@@ -16,10 +16,11 @@ ASSIGNMENT = {
     "llama-3.2-vision-11b": (40, 4096, 32, 8, 14336, 128256),
     "musicgen-large": (48, 2048, 32, 32, 8192, 2048),
     "xlstm-1.3b": (48, 2048, 4, 4, 0, 50304),
+    "deepseek-v2-lite": (27, 2048, 16, 16, 1408, 102400),
 }
 
 MOE = {"dbrx-132b": (16, 4), "qwen3-moe-30b-a3b": (128, 8),
-       "jamba-1.5-large-398b": (16, 2)}
+       "jamba-1.5-large-398b": (16, 2), "deepseek-v2-lite": (64, 6)}
 
 
 def test_all_archs_registered():
@@ -72,9 +73,9 @@ def test_hybrid_pattern_ratios():
 
 def test_cells_and_applicability():
     cells = all_cells()
-    assert len(cells) == 40                                 # 10 archs × 4
+    assert len(cells) == 44                                 # 11 archs × 4
     skipped = [(a, s) for a, s in cells if not shape_applicable(a, s)]
-    assert len(skipped) == 8                                # full-attn long_500k
+    assert len(skipped) == 9                                # full-attn long_500k
     assert all(s == "long_500k" for _, s in skipped)
     assert shape_applicable("jamba-1.5-large-398b", "long_500k")
     assert shape_applicable("xlstm-1.3b", "long_500k")
